@@ -7,13 +7,14 @@ path (DESIGN.md §11).  Also asserts the sharded segmentation is
 bit-identical to the single-device one — the benchmark doubles as a
 cheap end-to-end parity check.
 
-The XLA device count is process-global and fixed at backend init, so the
-measurement runs in a child process launched with
-``--xla_force_host_platform_device_count=8`` (a no-op for real
-accelerator platforms: the flag only affects *host* devices); the parent
-forwards the child's JSON.  On CPU the 8 "devices" share the machine's
-cores, so the sharded timings measure collective/partitioning overhead,
-not speedup — the number to watch off-TPU is the overhead ratio.
+On CPU the XLA device count is process-global and fixed at backend init,
+so the measurement runs in a child process launched with
+``--xla_force_host_platform_device_count=8``; the parent forwards the
+child's JSON.  On CPU the 8 "devices" share the machine's cores, so the
+sharded timings measure collective/partitioning overhead, not speedup —
+the number to watch off-TPU is the overhead ratio.  On an accelerator the
+parent already holds the chips, so it measures in-process, with the shard
+counts capped at ``jax.device_count()``.
 
 The size sweep also records the calibrated cost model's shard choice per
 size (``--shards auto``, DESIGN.md §18); under ``--check`` the parent
@@ -45,7 +46,7 @@ def _grid(size: int):
     return (size // 8, size // 8)
 
 
-def _measure() -> dict:
+def _measure(shard_counts=SHARDS) -> dict:
     import jax
     import numpy as np
 
@@ -65,7 +66,7 @@ def _measure() -> dict:
         for mode in modes:
             per = {}
             segmentations = {}
-            for shards in SHARDS:
+            for shards in shard_counts:
                 sess = api.Segmenter(
                     api.ExecutionConfig(
                         overseg_grid=_grid(size), mode=mode, shards=shards
@@ -87,12 +88,13 @@ def _measure() -> dict:
                     # --check gate holds the chosen count within 10% of
                     # the measured-best fixed count.
                     per["autotune"] = sess.cost_model().choose_shards(
-                        mode=mode, bucket=plan.bucket, candidates=SHARDS,
+                        mode=mode, bucket=plan.bucket, candidates=shard_counts,
                         max_em_iters=sess.config.max_em_iters,
                         max_map_iters=sess.config.max_map_iters,
                     ).as_dict()
             match = bool(
-                (segmentations[min(SHARDS)] == segmentations[max(SHARDS)]).all()
+                (segmentations[min(shard_counts)]
+                 == segmentations[max(shard_counts)]).all()
             )
             per["labels_match"] = match
             assert match, (
@@ -105,6 +107,7 @@ def _measure() -> dict:
     out = {
         "jax_backend": jax.default_backend(),
         "device_count": jax.device_count(),
+        "shard_counts": list(shard_counts),
         "image_shape": [BASE_SIZE, BASE_SIZE],
         "modes": sweep(image(BASE_SIZE), BASE_SIZE, MODES),
         # Size sweep on the fused static-pallas route only: it is the
@@ -121,12 +124,9 @@ def _measure() -> dict:
     return out
 
 
-def main() -> None:
-    if "--child" in sys.argv:
-        print(json.dumps(_measure()))
-        return
-
-    # jax stays unimported in the parent; repro.xla_env imports nothing heavy
+def _measure_in_child() -> dict:
+    """CPU only: measure in a child with forced host devices (this
+    process's device count is already fixed)."""
     from repro.xla_env import force_host_device_count
 
     root = pathlib.Path(__file__).resolve().parent.parent
@@ -140,26 +140,42 @@ def main() -> None:
         raise RuntimeError(
             f"sharded bench child failed:\n{proc.stdout}\n{proc.stderr}"
         )
-    result = json.loads(proc.stdout.splitlines()[-1])
+    return json.loads(proc.stdout.splitlines()[-1])
+
+
+def main() -> None:
+    if "--child" in sys.argv:
+        print(json.dumps(_measure()))
+        return
+
+    import jax
+
+    # One process per chip: a child could not reach the accelerator this
+    # process holds, so only the CPU backend spawns one.
+    if jax.default_backend() == "cpu":
+        result = _measure_in_child()
+    else:
+        result = _measure(tuple(sorted({1, min(max(SHARDS), jax.device_count())})))
+    shard_counts = result["shard_counts"]
     OUT_PATH.write_text(json.dumps(result, indent=2) + "\n")
 
     from benchmarks.common import print_csv
 
     rows = []
     for mode, per in result["modes"].items():
-        for shards in map(str, SHARDS):
+        for shards in map(str, shard_counts):
             d = per[shards]
             rows.append((mode, shards, d["optimize_seconds"],
                          d["compile_seconds"], per["labels_match"]))
     print_csv(
-        f"sharded EM: 1 vs {max(SHARDS)} shards "
+        f"sharded EM: 1 vs {max(shard_counts)} shards "
         f"({result['jax_backend']}, {result['device_count']} devices) -> {OUT_PATH}",
         ["mode", "shards", "optimize_s", "compile_s", "labels_match"],
         rows,
     )
     size_rows = []
     for size, per in result["sizes"].items():
-        for shards in map(str, SHARDS):
+        for shards in map(str, shard_counts):
             d = per[shards]
             size_rows.append((size, shards, d["optimize_seconds"],
                               d["em_iters"], per["labels_match"]))
@@ -178,7 +194,7 @@ def main() -> None:
         # wrong about absolute seconds, not about the ranking.
         for size, per in result["sizes"].items():
             chosen = per["autotune"]["shards"]
-            measured = {s: per[str(s)]["optimize_seconds"] for s in SHARDS}
+            measured = {s: per[str(s)]["optimize_seconds"] for s in shard_counts}
             best = min(measured.values())
             assert measured[chosen] <= best * 1.10, (
                 f"shard autotuner regressed at size {size}: chose "

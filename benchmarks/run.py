@@ -69,6 +69,9 @@ def main() -> None:
         common.CHECK = True
         args = [a for a in args if a != "--check"]
     want = args or list(SECTIONS)
+    from repro.xla_env import use_compile_cache
+
+    use_compile_cache()
     failures = []
     for name in want:
         assert name in SECTIONS, f"unknown section {name!r}; have {SECTIONS}"

@@ -29,8 +29,8 @@ import itertools
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
-import numpy as np
 import jax
+from jax.experimental import pallas as pl
 
 from .findings import Finding
 from .jaxpr_lint import iter_eqns
@@ -73,20 +73,11 @@ def find_pallas_calls(closed) -> Iterator[object]:
 
 
 def _dimension_semantics(params) -> Optional[Tuple[str, ...]]:
-    """Extract declared dimension_semantics from compiler_params (the
-    mosaic dict form used by jax 0.4.x), else None."""
-    cp = params.get("compiler_params") or {}
-    candidates = [cp]
-    if isinstance(cp, dict):
-        candidates += [v for v in cp.values() if isinstance(v, dict)]
-    for c in candidates:
-        if isinstance(c, dict):
-            ds = c.get("dimension_semantics")
-        else:
-            ds = getattr(c, "dimension_semantics", None)
-        if ds is not None:
-            return tuple(str(x) for x in ds)
-    return None
+    """Declared dimension_semantics of a pallas_call — the Mosaic
+    ``CompilerParams`` under ``compiler_params["mosaic_tpu"]`` — else None."""
+    mosaic = (params.get("compiler_params") or {}).get("mosaic_tpu")
+    ds = getattr(mosaic, "dimension_semantics", None)
+    return tuple(str(x) for x in ds) if ds is not None else None
 
 
 def _eval_index_map(bm, point: Sequence[int]) -> Tuple[int, ...]:
@@ -122,11 +113,11 @@ def _dependent_axes(bm, grid: Sequence[int]) -> List[int]:
 
 
 def _block_dims(bm) -> List[Optional[int]]:
-    """Block shape as ints (None for mapped/squeezed dims)."""
-    dims = []
-    for b in bm.block_shape:
-        dims.append(int(b) if isinstance(b, (int, np.integer)) else None)
-    return dims
+    """Block shape as ints (None for squeezed/element/bounded-slice dims)."""
+    return [
+        int(b.block_size) if isinstance(b, pl.Blocked) else None
+        for b in bm.block_shape
+    ]
 
 
 def check_pallas_call(eqn, kernel_name: str) -> KernelReport:
@@ -143,52 +134,49 @@ def check_pallas_call(eqn, kernel_name: str) -> KernelReport:
         origin = getattr(bm, "origin", "")
         is_output = "output" in str(origin)
         label = f"kernel:{kernel_name}/{origin or f'operand[{bi}]'}"
-        arr = getattr(bm, "array_shape_dtype", None)
-        arr_shape = tuple(int(s) for s in arr.shape) if arr is not None else None
+        arr_shape = tuple(int(s) for s in bm.array_aval.shape)
         blocks = _block_dims(bm)
 
         # PL103 — divisibility per (padded) array dim.  Every memory
         # space Mosaic exposes (VMEM/SMEM/ANY) requires whole blocks in
         # this codebase's padded-layout regime; a remainder block means
         # a silent partial-tile read/write.
-        if arr_shape is not None:
-            for d, (a, b) in enumerate(zip(arr_shape, blocks)):
-                if b is not None and b > 0 and a % b != 0:
-                    report.findings.append(
-                        Finding(
-                            "PL103", "error", f"{label}/dim[{d}]",
-                            f"block shape {b} does not divide array dim "
-                            f"{a} (axis {d}); pad the operand or shrink "
-                            "the block",
-                        )
+        for d, (a, b) in enumerate(zip(arr_shape, blocks)):
+            if b is not None and b > 0 and a % b != 0:
+                report.findings.append(
+                    Finding(
+                        "PL103", "error", f"{label}/dim[{d}]",
+                        f"block shape {b} does not divide array dim "
+                        f"{a} (axis {d}); pad the operand or shrink "
+                        "the block",
                     )
+                )
 
         # PL102 — index map stays inside the array's block extent at
         # every sampled grid point.
-        if arr_shape is not None:
-            extents = [
-                (-(-a // b) if (b and b > 0) else None)
-                for a, b in zip(arr_shape, blocks)
-            ]
-            oob_reported = False
-            for pt in _grid_samples(grid):
-                idx = _eval_index_map(bm, pt)
-                for d, (i, ext) in enumerate(zip(idx, extents)):
-                    if ext is None:
-                        continue
-                    if i < 0 or i >= ext:
-                        report.findings.append(
-                            Finding(
-                                "PL102", "error", f"{label}/dim[{d}]",
-                                f"index map yields block index {i} at grid "
-                                f"point {tuple(pt)} but axis {d} has only "
-                                f"{ext} block(s)",
-                            )
+        extents = [
+            (-(-a // b) if (b and b > 0) else None)
+            for a, b in zip(arr_shape, blocks)
+        ]
+        oob_reported = False
+        for pt in _grid_samples(grid):
+            idx = _eval_index_map(bm, pt)
+            for d, (i, ext) in enumerate(zip(idx, extents)):
+                if ext is None:
+                    continue
+                if i < 0 or i >= ext:
+                    report.findings.append(
+                        Finding(
+                            "PL102", "error", f"{label}/dim[{d}]",
+                            f"index map yields block index {i} at grid "
+                            f"point {tuple(pt)} but axis {d} has only "
+                            f"{ext} block(s)",
                         )
-                        oob_reported = True
-                        break
-                if oob_reported:
+                    )
+                    oob_reported = True
                     break
+            if oob_reported:
+                break
 
         # PL101 / PL104 — revisited output axes vs. declared semantics.
         if is_output:

@@ -1,41 +1,19 @@
-"""Version compatibility shims for the pinned accelerator image.
+"""The one spelling of Pallas TPU compiler parameters the kernels share.
 
-``jax.shard_map`` graduated from ``jax.experimental.shard_map`` only in
-newer JAX releases (which also renamed ``check_rep`` to ``check_vma``);
-the image pins a version where it is still experimental.  Import it from
-here so call sites can use the modern spelling everywhere.
+Every ``pallas_call`` in ``repro.kernels`` declares its grid's dimension
+semantics through :func:`tpu_compiler_params`, so a change in how the
+installed JAX spells Mosaic's options is a one-line fix here.
 """
 
 from __future__ import annotations
 
-import inspect
-
-import jax
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pre-graduation JAX
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_ACCEPTS_CHECK_VMA = "check_vma" in inspect.signature(_shard_map).parameters
+from jax.experimental.pallas import tpu as pltpu
 
 
-def shard_map(*args, **kwargs):
-    if not _ACCEPTS_CHECK_VMA:
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        # Old shard_map's replication checker has no rule for `while` (and
-        # friends) that newer JAX handles fine; default the check off so
-        # loop-carrying bodies work identically across versions.
-        kwargs.setdefault("check_rep", False)
-    return _shard_map(*args, **kwargs)
+def tpu_compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
+    """Mosaic compiler parameters declaring each grid axis ``"parallel"``
+    (disjoint output blocks) or ``"arbitrary"`` (sequential, in order)."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
-def axis_size(axis_name) -> jax.Array:
-    """``jax.lax.axis_size`` for JAX versions that predate it."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-__all__ = ["shard_map", "axis_size"]
+__all__ = ["tpu_compiler_params"]

@@ -263,26 +263,24 @@ def reduce_by_key(
                     return kops.segment_reduce(
                         values, segment_ids, num_segments, op, backend=resolved
                     )
-                # Surface the downgrade (at trace time) so parity/benchmark
-                # runs that *explicitly* named a pallas backend (argument,
-                # env var, or override) know this reduction ran on XLA
-                # instead; auto-detected backends fall back silently (the
-                # fallback is the intended routing).
-                if kops.backend_explicitly_requested(backend):
-                    import warnings
+                # Surface the downgrade (at trace time), whether the pallas
+                # backend was named or auto-detected, so a run never
+                # measures this reduction on XLA without saying so.
+                import warnings
 
-                    reason = (
-                        f"num_segments={num_segments} exceeds "
-                        f"MAX_REDUCE_SEGMENTS={kops.MAX_REDUCE_SEGMENTS}"
-                        if supported
-                        else f"op={op!r}/dtype={values.dtype}/ndim={values.ndim}"
-                        " unsupported by the pallas kernel"
-                    )
-                    warnings.warn(
-                        f"reduce_by_key: {reason}; staying on XLA instead of "
-                        f"{resolved!r}",
-                        stacklevel=3,
-                    )
+                reason = (
+                    f"num_segments={num_segments} exceeds "
+                    f"MAX_REDUCE_SEGMENTS={kops.MAX_REDUCE_SEGMENTS}"
+                    if supported
+                    else f"op={op!r}/dtype={values.dtype}/ndim={values.ndim}"
+                    " unsupported by the pallas kernel"
+                )
+                warnings.warn(
+                    f"reduce_by_key: {reason}; staying on XLA instead of "
+                    f"{resolved!r}",
+                    kops.BackendDowngradeWarning,
+                    stacklevel=3,
+                )
         kwargs = dict(
             num_segments=num_segments, indices_are_sorted=indices_are_sorted
         )

@@ -41,7 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core.pmrf import collectives
 from repro.core.pmrf import em as em_mod
 from repro.core.pmrf import energy as E
@@ -168,11 +167,16 @@ def run_em_sharded(
     spec_e = P(axis)      # element-partitioned
     spec_r = P()          # replicated
 
+    # check_vma=False: a pallas_call inside shard_map declares no
+    # varying-axes type for its outputs, which the check requires.  The
+    # outputs are replicated by construction (every cross-shard quantity
+    # goes through the collective context).
     @partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec_e,) * 7 + (spec_r,) * 4,
         out_specs=spec_r,
+        check_vma=False,
     )
     def run(
         vertex, hood_id, valid, rep_old, rep_test, rep_hood, rep_valid,

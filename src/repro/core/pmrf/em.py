@@ -346,10 +346,9 @@ def _em_driver(
     """
     n_hoods = hoods.n_hoods
     mode = config.mode
-    # Threaded raw so the dispatch layer can distinguish an explicit
-    # backend request from "auto" (only explicit downgrades warn); each
-    # layer resolves at trace time — "auto" follows env/override/platform,
-    # and changing those after a trace is cached will not retrace.
+    # Threaded raw; each layer resolves at trace time — "auto" follows
+    # env/override/platform, and changing those after a trace is cached
+    # will not retrace.
     kops.resolve_backend(config.backend)  # validate early: raises on unknown
     backend = config.backend
     sctx = (

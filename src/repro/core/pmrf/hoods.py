@@ -19,17 +19,17 @@ oldIndex, hoodId — the "repHoods" simulated, memory-free Gather).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import dpp
 from repro.core.pmrf.cliques import CliqueSet
 from repro.core.pmrf.graph import RegionGraph
-
-import jax
 
 
 @jax.tree_util.register_dataclass
@@ -64,29 +64,58 @@ class Hoods:
 def build_hoods(graph: RegionGraph, cliques: CliqueSet) -> Hoods:
     n = graph.n_regions
     c = cliques.n_cliques
-    w = cliques.width
     if c == 0:
         raise ValueError("no cliques — empty graph?")
 
-    members = jnp.asarray(cliques.members)            # (C, W)
+    # Step 2's static capacity, counted on the host from the graph: one
+    # candidate per (clique member, 1-hop neighbor) pair.
+    members = cliques.members
+    deg = np.diff(graph.csr_offsets)
+    neighbor_capacity = int(deg[members[members >= 0]].sum())
+
+    # Every shape below depends on the slice, so the device work is one
+    # jitted program (one compile per slice shape) rather than dozens of
+    # eagerly dispatched ops that each compile per shape.
+    vertex, hood_id, valid, sizes, hood_offsets, rep = _hood_arrays(
+        jnp.asarray(members),
+        jnp.asarray(graph.csr_offsets),
+        jnp.asarray(graph.csr_neighbors),
+        n_regions=n,
+        neighbor_capacity=neighbor_capacity,
+    )
+    return Hoods(
+        vertex=vertex,
+        hood_id=hood_id,
+        valid=valid,
+        sizes=sizes,
+        offsets=hood_offsets,
+        n_hoods=c,
+        n_regions=n,
+        n_elements=int(np.asarray(jnp.sum(valid.astype(jnp.int32)))),
+        rep_old_index=rep[0],
+        rep_test_label=rep[1],
+        rep_hood_id=rep[2],
+        rep_valid=rep[3],
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("n_regions", "neighbor_capacity"))
+def _hood_arrays(
+    members, offsets, neighbors, *, n_regions: int, neighbor_capacity: int
+):
+    """Steps 1, 3 and 4 plus the label replication, for :func:`build_hoods`."""
+    n = n_regions
+    c, w = members.shape
     members_flat = members.reshape(-1)                # (C*W,)
     clique_of_slot = jnp.repeat(jnp.arange(c, dtype=jnp.int32), w)
     valid_slot = members_flat >= 0
     n_slots = c * w
-
-    offsets = jnp.asarray(graph.csr_offsets)
-    neighbors = jnp.asarray(graph.csr_neighbors)
     deg = offsets[1:] - offsets[:-1]
 
     safe_member = jnp.where(valid_slot, members_flat, 0)
 
     # -- Step 1: Find Neighbors (Map) — per-slot neighbor counts. ----------
     slot_counts = jnp.where(valid_slot, deg[safe_member], 0).astype(jnp.int32)
-
-    # -- Step 2: Count Neighbors (Scan) — allocate candidates array. -------
-    # Static capacity: all neighbor slots + the clique members themselves.
-    neighbor_capacity = int(np.asarray(jnp.sum(slot_counts)))
-    total_capacity = neighbor_capacity + n_slots
 
     # -- Step 3: Get Neighbors (Map over expanded lanes). ------------------
     src_slot, rank = dpp.expand_with_rank(slot_counts, neighbor_capacity)
@@ -118,7 +147,7 @@ def build_hoods(graph: RegionGraph, cliques: CliqueSet) -> Hoods:
         dpp.compound_key(member_keys_cid, member_keys_v, span, major_span=c + 1),
         sentinel,
     )
-    keys = jnp.concatenate([key_mem, key_nb])  # (total_capacity,)
+    keys = jnp.concatenate([key_mem, key_nb])  # (neighbor_capacity + n_slots,)
 
     # -- Step 4: Remove Duplicate Neighbors (SortByKey + Unique). ----------
     (sorted_keys,) = dpp.sort_by_key(keys)
@@ -139,26 +168,11 @@ def build_hoods(graph: RegionGraph, cliques: CliqueSet) -> Hoods:
         op="add",
     )[:c]
     hood_offsets = dpp.counts_to_offsets(sizes)
-    n_elements = int(np.asarray(jnp.sum(valid.astype(jnp.int32))))
 
     # -- Replication by label (paper: Map + Scan + Gather, memory-free). ---
     h_pad = int(vertex.shape[0])
     rep = _build_replication(hood_id, valid, sizes, hood_offsets, c, h_pad)
-
-    return Hoods(
-        vertex=vertex,
-        hood_id=jnp.where(valid, hood_id, c),
-        valid=valid,
-        sizes=sizes,
-        offsets=hood_offsets,
-        n_hoods=c,
-        n_regions=n,
-        n_elements=n_elements,
-        rep_old_index=rep[0],
-        rep_test_label=rep[1],
-        rep_hood_id=rep[2],
-        rep_valid=rep[3],
-    )
+    return vertex, jnp.where(valid, hood_id, c), valid, sizes, hood_offsets, rep
 
 
 def pad_hoods(
@@ -194,6 +208,19 @@ def pad_hoods(
         h.capacity, h.n_hoods, h.n_regions, h.n_elements,
     ):
         return h
+    return _pad_hoods(
+        h, capacity=capacity, n_hoods=n_hoods, n_regions=n_regions,
+        n_elements=n_elements,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("capacity", "n_hoods", "n_regions", "n_elements")
+)
+def _pad_hoods(h: Hoods, *, capacity: int, n_hoods: int, n_regions: int,
+               n_elements: int) -> Hoods:
+    """The array work of :func:`pad_hoods`, one program per (slice, bucket)
+    shape pair."""
 
     def pad1(x, fill, total):
         return jnp.full((total,), fill, x.dtype).at[: x.shape[0]].set(x)

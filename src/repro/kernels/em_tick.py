@@ -40,12 +40,15 @@ tolerance tier, tests/test_golden.py).
 Bitwise contract at f32: the energy expressions, min/argmin fold, and
 the per-hood/vote one-hot contractions replicate ``map_step.py``'s op
 order exactly, so ``min_e``/``arg``/``hood_e``/``votes`` are bit-identical
-to the label-replicated kernel.  The M-step sums are one-hot dots whose
-accumulation order differs from ``jax.ops.segment_sum``'s element order,
-so mu/sigma may differ in final ulps from the unfused composition (the
-reference ``ref.fused_em_tick`` keeps segment_sum order and stays
-bitwise against the golden fixtures); the convergence predicate is the
-same arithmetic as ``em._window_converged`` on identical hood sums.
+to the label-replicated kernel.  The compiled kernel's M-step sums are
+one-hot MXU dots whose accumulation order differs from
+``jax.ops.segment_sum``'s element order, so on the chip mu/sigma may
+differ in final ulps from the unfused composition.  In interpret mode the
+kernel takes them with ``segment_sum`` instead, the order of the
+reference ``ref.fused_em_tick`` and of the golden oracle, so the
+interpreted kernel stays bitwise against the golden fixtures; the
+convergence predicate is the same arithmetic as ``em._window_converged``
+on identical hood sums.
 
 Inputs (all (H,) f32 unless noted):
   y, w, nall_e, xf, valid     as in ``map_step.py``
@@ -66,11 +69,13 @@ SEG_ALIGN with zero weight — all padding is inert in every reduction.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.compat import tpu_compiler_params
 
 BLOCK = 1024     # hood elements per value tile
 SEG_ALIGN = 128  # segment-axis padding (MXU lane width)
@@ -85,26 +90,61 @@ def label_energies_blocked(
     (whole (K, H) array) so both paths run the *identical* elementwise
     op sequence — and, at f32, the identical sequence as
     ``energy.label_energies`` / ``map_step.py``, keeping argmins bitwise.
-    ``precision="bf16"`` casts every energy operand to bfloat16; callers
-    cast the result back to f32 before accumulating.
+    ``mu``/``sig`` arrive as (K, 1) columns: the TPU lowering refuses a
+    bf16 (K,) -> (K, 1) shape cast inside the kernel.
+    ``precision="bf16"`` casts every energy operand to bfloat16; the
+    result comes back widened to f32, so the min/argmin fold and every
+    accumulator run in f32 on both paths (v5e has no bf16 compare).  The
+    compiled kernel's bf16 energies widen exactly; on XLA:CPU callers
+    apply :func:`round_to_bf16` first (see there).
     """
     cd = jnp.bfloat16 if precision == "bf16" else jnp.float32
+    # The label-match mask is compared in f32 whatever the precision (the
+    # labels are small integers, exact in both): Mosaic has no float iota
+    # and v5e has no bf16 compare.
+    labf = jax.lax.broadcasted_iota(jnp.int32, cnt.shape, 0).astype(jnp.float32)
+    eq = (xf.astype(jnp.float32)[None, :] == labf).astype(cd)
     y = y.astype(cd)
     w = w.astype(cd)
     nall = nall.astype(cd)
-    xf = xf.astype(cd)
     valid = valid.astype(cd)
     cnt = cnt.astype(cd)
-    mu = mu.astype(cd)[:, None]
-    sig = sig.astype(cd)[:, None]
+    mu = mu.astype(cd)
+    sig = sig.astype(cd)
     beta = jnp.asarray(beta).astype(cd)
-    labf = jax.lax.broadcasted_iota(jnp.float32, cnt.shape, 0).astype(cd)
     denom = jnp.maximum(nall - 1.0, 1.0)
     d = y[None, :] - mu
-    eq = (xf[None, :] == labf).astype(cd)
-    return w[None, :] * (d * d / (2.0 * sig * sig) + jnp.log(sig)) + beta * jnp.maximum(
+    e = w[None, :] * (d * d / (2.0 * sig * sig) + jnp.log(sig)) + beta * jnp.maximum(
         (nall[None, :] - cnt) - (1.0 - eq), 0.0
     ) / denom[None, :] * valid[None, :]
+    return e.astype(jnp.float32)
+
+
+def round_to_bf16(x):
+    """Round f32 ``x`` to the nearest bfloat16 (ties to even), kept in f32.
+
+    A no-op on values already in bfloat16, as the compiled kernel's are.
+    XLA:CPU may carry bf16 intermediates at f32 ("excess precision")
+    differently in the kernel's interpreted blocks and in the reference's
+    whole-array graph; both round here, with integer ops that no float
+    simplification can remove, to pin the same bf16 energies.
+    """
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    b = b + 0x7FFF + (jax.lax.shift_right_logical(b, 16) & 1)
+    return jax.lax.bitcast_convert_type(b & -0x10000, jnp.float32)
+
+
+def fold_min_argmin(e):
+    """Per-column (min, argmin) of a (K, N) energy tile as an unrolled fold
+    over the K rows; strict '<' keeps the lowest label on ties (bitwise
+    ``jnp.argmin`` semantics).  Shared by both fused kernels."""
+    min_e = e[0]
+    arg = jnp.zeros(e.shape[1:], jnp.int32)
+    for l in range(1, e.shape[0]):
+        take = e[l] < min_e
+        min_e = jnp.where(take, e[l], min_e)
+        arg = jnp.where(take, l, arg).astype(jnp.int32)
+    return min_e, arg
 
 
 def _kernel(
@@ -132,6 +172,7 @@ def _kernel(
     sentinel: int,
     conv_tol: float,
     precision: str,
+    interpret: bool,
 ):
     p = pl.program_id(0)   # pass: 0 = counts, 1 = map + finalize
     i = pl.program_id(1)   # element block (innermost, sequential)
@@ -175,19 +216,12 @@ def _kernel(
             y_ref[...], w_ref[...], cnt_blk, nall_ref[...], xf, valid,
             mu_ref[...], sig_ref[...], beta_ref[0], precision=precision,
         )
-        # Unrolled min/argmin fold over the K rows; strict '<' keeps the
-        # lowest label on ties — bitwise jnp.argmin semantics, and the
-        # exact fold ``map_step.py`` runs across its label grid steps.
-        min_e = e[0]
-        arg = jnp.zeros((BLOCK,), jnp.int32)
-        for l in range(1, n_labels):
-            take = e[l] < min_e
-            min_e = jnp.where(take, e[l], min_e)
-            arg = jnp.where(take, l, arg).astype(jnp.int32)
-        min_f = min_e.astype(jnp.float32)
+        if interpret and precision == "bf16":
+            e = round_to_bf16(e)
+        min_e, arg = fold_min_argmin(e)
 
         hood_e_ref[...] += jnp.dot(
-            onehot_h, min_f * valid, preferred_element_type=jnp.float32
+            onehot_h, min_e * valid, preferred_element_type=jnp.float32
         )
         v_rows = votes_ref.shape[1]
         rows_v = jax.lax.broadcasted_iota(jnp.int32, (v_rows, BLOCK), 0)
@@ -218,13 +252,21 @@ def _kernel(
         # the NEW labels (padded regions carry zero weight — inert).
         wr = rw_ref[...]
         yr = rm_ref[...]
-        rows_k = jax.lax.broadcasted_iota(jnp.int32, (n_labels, r_pad), 0)
-        onehot_l = (rows_k == lab[None, :]).astype(jnp.float32)
-        sum_w = jnp.dot(onehot_l, wr, preferred_element_type=jnp.float32)
-        sum_wy = jnp.dot(onehot_l, wr * yr, preferred_element_type=jnp.float32)
-        sum_wyy = jnp.dot(
-            onehot_l, wr * yr * yr, preferred_element_type=jnp.float32
-        )
+        if interpret:
+            # XLA:CPU's dot does not add in element order; a scatter-add
+            # does, which keeps the interpreted kernel bitwise with the
+            # reference and the golden oracle.
+            def msum(v):
+                return jax.ops.segment_sum(v, lab, num_segments=n_labels)
+        else:
+            rows_k = jax.lax.broadcasted_iota(jnp.int32, (n_labels, r_pad), 0)
+            onehot_l = (rows_k == lab[None, :]).astype(jnp.float32)
+
+            def msum(v):
+                return jnp.dot(onehot_l, v, preferred_element_type=jnp.float32)
+        sum_w = msum(wr)
+        sum_wy = msum(wr * yr)
+        sum_wyy = msum(wr * yr * yr)
 
         # Convergence window — the same arithmetic as em._window_converged
         # on [hood_e, hist[0], ..., hist[W-1]]; padded hoods compare
@@ -269,14 +311,12 @@ def fused_em_tick_pallas(
     n_vertices: int,
     precision: str = "f32",
     conv_tol: float = 1.0e-4,
-    interpret: Optional[bool] = None,
+    interpret: bool,
 ) -> Tuple[jax.Array, ...]:
     """One fused launch for the whole EM tick body.
 
     Returns ``(labels, hood_e, votes, conv, sum_w, sum_wy, sum_wyy)``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if precision not in ("f32", "bf16"):
         raise ValueError(f"unknown precision {precision!r}; have ('f32', 'bf16')")
     n_labels = int(mu.shape[0])
@@ -318,12 +358,13 @@ def fused_em_tick_pallas(
             sentinel=n_vertices - 1,
             conv_tol=float(conv_tol),
             precision=precision,
+            interpret=interpret,
         ),
         grid=(2, n_blocks),
         in_specs=[
             full((1,)),            # beta
-            full((n_labels,)),     # mu
-            full((n_labels,)),     # sigma
+            full((n_labels, 1)),   # mu (column)
+            full((n_labels, 1)),   # sigma (column)
             blockspec_e,           # y
             blockspec_e,           # w
             blockspec_e,           # nall_e
@@ -354,14 +395,13 @@ def fused_em_tick_pallas(
         # declare the whole grid sequential ("arbitrary") explicitly; the
         # analysis race checker (PL104, DESIGN.md §15) requires the
         # revisit-safety assumption to be stated, not inherited.
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary", "arbitrary"))
-        ),
+        compiler_params=tpu_compiler_params("arbitrary", "arbitrary"),
         interpret=interpret,
+        name="fused_em_tick",
     )(
         jnp.asarray(beta, jnp.float32).reshape(1),
-        mu.astype(jnp.float32),
-        sigma.astype(jnp.float32),
+        mu.astype(jnp.float32).reshape(n_labels, 1),
+        sigma.astype(jnp.float32).reshape(n_labels, 1),
         padf(y),
         padf(w),
         padf(nall_e),

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.compat import tpu_compiler_params
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1.0e30
@@ -140,12 +142,8 @@ def flash_attention_pallas(
         # ("arbitrary"); batch/head/query-block axes write disjoint
         # tiles and are parallel.  Declared for the analysis race
         # checker (PL101/PL104, DESIGN.md §15).
-        compiler_params=dict(
-            mosaic=dict(
-                dimension_semantics=(
-                    "parallel", "parallel", "parallel", "arbitrary"
-                )
-            )
+        compiler_params=tpu_compiler_params(
+            "parallel", "parallel", "parallel", "arbitrary"
         ),
         interpret=interpret,
     )(q, k, v)

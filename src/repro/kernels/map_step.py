@@ -12,17 +12,16 @@ iteration body into one launch, for any label count K (DESIGN.md §13):
     per hood h:      hood_e[h]   = sum_{e in h} min_e[e]         (one-hot dot)
     per (l, vertex): votes[l,v]  = #{e: vertex[e]=v, arg[e]=l}   (one-hot dot)
 
-The grid gains a **label dimension**: ``grid = (n_blocks, K)`` with the
-label axis innermost (sequential on TPU), so each value block is revisited
-K times.  Label step l computes e_l from its (1, BLOCK) slice of the
-per-(element, label) neighborhood-count input and its (1,) slices of
-mu/sigma, folds it into the running per-element min/argmin held in the
-revisited output blocks, and the final label step performs the keyed
-reductions as masked one-hot contractions on the MXU — including one vote
-contraction per label into the (K, n_vertices) vote field.  The K=2
-instance computes bit-identical energies, argmins, hood sums, and votes to
-the historical binary kernel (the count rewrite only touches integer-exact
-quantities).
+The grid walks the element blocks only; all K labels of a block are
+computed at once as a ``(K, BLOCK)`` tile from the block's slice of the
+per-(element, label) neighborhood-count input and the ``(K, 1)`` mu/sigma
+columns (the energy expression is ``em_tick.label_energies_blocked``, the
+same one the fused EM tick runs).  An unrolled fold over the K rows gives
+the per-element min/argmin, and the keyed reductions run as masked
+one-hot contractions on the MXU — including one vote contraction per
+label into the (K, n_vertices) vote field.  The K=2 instance computes
+bit-identical energies, argmins, hood sums, and votes to the historical
+binary kernel (the count rewrite only touches integer-exact quantities).
 
 Inputs (all (H,) unless noted):
   y       region mean intensity (pre-gathered per element)
@@ -46,11 +45,14 @@ zeroes their contributions anyway) and block-padding lanes (ids forced to
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.compat import tpu_compiler_params
+from repro.kernels.em_tick import fold_min_argmin, label_energies_blocked
 
 BLOCK = 1024     # hood elements per value tile
 SEG_ALIGN = 128  # segment-axis padding (MXU lane width)
@@ -76,70 +78,40 @@ def _kernel(
     n_labels: int,
 ):
     i_v = pl.program_id(0)
-    l = pl.program_id(1)      # label grid dimension (innermost, sequential)
-
-    beta = beta_ref[0]
-    mu_l = mu_ref[0]
-    sig_l = sig_ref[0]
-
-    y = y_ref[...]
-    w = w_ref[...]
-    cnt = cnt_ref[0, :]
-    nall = nall_ref[...]
-    xf = xf_ref[...]
     valid = valid_ref[...]
 
-    # Energy expressions mirror energy.label_energies exactly (same op
-    # order) so the per-element argmin is bit-identical to the static mode.
-    denom = jnp.maximum(nall - 1.0, 1.0)
-    d = y - mu_l
-    eq = (xf == l.astype(jnp.float32)).astype(jnp.float32)
-    e = w * (d * d / (2.0 * sig_l * sig_l) + jnp.log(sig_l)) + beta * jnp.maximum(
-        (nall - cnt) - (1.0 - eq), 0.0
-    ) / denom * valid
+    # (K, BLOCK) energies, op order identical to energy.label_energies so
+    # the per-element argmin is bit-identical to the static mode.
+    e = label_energies_blocked(
+        y_ref[...], w_ref[...], cnt_ref[...], nall_ref[...], xf_ref[...],
+        valid, mu_ref[...], sig_ref[...], beta_ref[0],
+    )
+    min_e, arg = fold_min_argmin(e)
+    min_ref[...] = min_e
+    arg_ref[...] = arg
 
-    # Running per-element min/argmin across the label grid steps (the
-    # min/arg blocks are revisited: same block index for every l).
-    @pl.when(l == 0)
-    def _first_label():
-        min_ref[...] = e
-        arg_ref[...] = jnp.zeros_like(arg_ref)
-
-    @pl.when(l > 0)
-    def _fold_label():
-        prev = min_ref[...]
-        take = e < prev                       # strict: ties keep lowest l
-        min_ref[...] = jnp.where(take, e, prev)
-        arg_ref[...] = jnp.where(take, l, arg_ref[...]).astype(jnp.int32)
-
-    @pl.when((i_v == 0) & (l == 0))
+    @pl.when(i_v == 0)
     def _init():
         hood_e_ref[...] = jnp.zeros_like(hood_e_ref)
         votes_ref[...] = jnp.zeros_like(votes_ref)
 
-    # Keyed reductions as one-hot contractions (MXU) at the final label
-    # step, when the block's min/arg are complete.  The grid's value and
-    # label dimensions are sequential on TPU, so += accumulation is safe.
-    @pl.when(l == n_labels - 1)
-    def _reduce():
-        min_e = min_ref[...]
-        arg = arg_ref[...]
+    # Keyed reductions as one-hot contractions (MXU); the element-block
+    # axis is sequential, so += accumulation is safe.
+    s_rows = hood_e_ref.shape[0]
+    rows_h = jax.lax.broadcasted_iota(jnp.int32, (s_rows, BLOCK), 0)
+    onehot_h = (rows_h == hood_ref[...][None, :]).astype(jnp.float32)
+    hood_e_ref[...] += jnp.dot(
+        onehot_h, min_e * valid, preferred_element_type=jnp.float32
+    )
 
-        s_rows = hood_e_ref.shape[0]
-        rows_h = jax.lax.broadcasted_iota(jnp.int32, (s_rows, BLOCK), 0)
-        onehot_h = (rows_h == hood_ref[...][None, :]).astype(jnp.float32)
-        hood_e_ref[...] += jnp.dot(
-            onehot_h, min_e * valid, preferred_element_type=jnp.float32
+    v_rows = votes_ref.shape[1]
+    rows_v = jax.lax.broadcasted_iota(jnp.int32, (v_rows, BLOCK), 0)
+    onehot_v = (rows_v == vert_ref[...][None, :]).astype(jnp.float32)
+    for l2 in range(n_labels):
+        sel = (arg == l2).astype(jnp.float32) * valid
+        votes_ref[l2, :] += jnp.dot(
+            onehot_v, sel, preferred_element_type=jnp.float32
         )
-
-        v_rows = votes_ref.shape[1]
-        rows_v = jax.lax.broadcasted_iota(jnp.int32, (v_rows, BLOCK), 0)
-        onehot_v = (rows_v == vert_ref[...][None, :]).astype(jnp.float32)
-        for l2 in range(n_labels):
-            sel = (arg == l2).astype(jnp.float32) * valid
-            votes_ref[l2, :] += jnp.dot(
-                onehot_v, sel, preferred_element_type=jnp.float32
-            )
 
 
 @functools.partial(
@@ -160,11 +132,9 @@ def fused_map_step_pallas(
     *,
     n_hoods: int,
     n_vertices: int,
-    interpret: Optional[bool] = None,
+    interpret: bool,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One fused launch for the whole static-mode K-ary MAP iteration body."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n_labels = int(mu.shape[0])
     n = y.shape[0]
     n_pad = -(-n // BLOCK) * BLOCK
@@ -183,17 +153,21 @@ def fused_map_step_pallas(
         cnt_e.astype(jnp.float32)
     )
 
-    blockspec_e = pl.BlockSpec((BLOCK,), lambda i, l: (i,))
+    blockspec_e = pl.BlockSpec((BLOCK,), lambda i: (i,))
+
+    def full(shape):
+        return pl.BlockSpec(shape, lambda i, _z=(0,) * len(shape): _z)
+
     min_e, arg, hood_e, votes = pl.pallas_call(
         functools.partial(_kernel, n_labels=n_labels),
-        grid=(n_pad // BLOCK, n_labels),
+        grid=(n_pad // BLOCK,),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, l: (0,)),       # beta
-            pl.BlockSpec((1,), lambda i, l: (l,)),       # mu[l]
-            pl.BlockSpec((1,), lambda i, l: (l,)),       # sigma[l]
+            full((1,)),                                  # beta
+            full((n_labels, 1)),                         # mu (column)
+            full((n_labels, 1)),                         # sigma (column)
             blockspec_e,                                 # y
             blockspec_e,                                 # w
-            pl.BlockSpec((1, BLOCK), lambda i, l: (l, i)),  # cnt_e[l]
+            pl.BlockSpec((n_labels, BLOCK), lambda i: (0, i)),  # cnt_e
             blockspec_e,                                 # nall_e
             blockspec_e,                                 # xf
             blockspec_e,                                 # valid
@@ -201,10 +175,10 @@ def fused_map_step_pallas(
             blockspec_e,                                 # vertex
         ],
         out_specs=[
-            blockspec_e,                                 # min_e (revisited)
-            blockspec_e,                                 # arg (revisited)
-            pl.BlockSpec((s_pad,), lambda i, l: (0,)),   # accumulated
-            pl.BlockSpec((n_labels, r_pad), lambda i, l: (0, 0)),
+            blockspec_e,                                 # min_e
+            blockspec_e,                                 # arg
+            full((s_pad,)),                              # accumulated
+            full((n_labels, r_pad)),                     # accumulated
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_pad,), jnp.float32),
@@ -212,20 +186,16 @@ def fused_map_step_pallas(
             jax.ShapeDtypeStruct((s_pad,), jnp.float32),
             jax.ShapeDtypeStruct((n_labels, r_pad), jnp.float32),
         ],
-        # Every output block is revisited across the grid: min/arg carry
-        # the running minimum along the label axis, and hood_e/votes
-        # accumulate over BOTH axes.  Declare the whole grid sequential
-        # ("arbitrary") instead of relying on Mosaic's implicit default —
-        # the analysis race checker (PL104, DESIGN.md §15) requires the
-        # revisit-safety assumption to be stated, not inherited.
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary", "arbitrary"))
-        ),
+        # hood_e/votes accumulate over the element-block axis, so it runs
+        # sequentially ("arbitrary") — declared explicitly for the analysis
+        # race checker (PL104, DESIGN.md §15).
+        compiler_params=tpu_compiler_params("arbitrary"),
         interpret=interpret,
+        name="fused_map_step",
     )(
         jnp.asarray(beta, jnp.float32).reshape(1),
-        mu.astype(jnp.float32),
-        sigma.astype(jnp.float32),
+        mu.astype(jnp.float32).reshape(n_labels, 1),
+        sigma.astype(jnp.float32).reshape(n_labels, 1),
         padf(y),
         padf(w),
         cnt_pad,

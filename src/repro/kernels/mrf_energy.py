@@ -22,11 +22,12 @@ Outputs: min_e (H,) float32, arg (H,) int32.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.compat import tpu_compiler_params
 
 BLOCK = 2048  # elements per tile (VMEM: ~7 input/output f32 lanes * BLOCK)
 
@@ -69,11 +70,8 @@ def mrf_min_energy_pallas(
     sigma: jax.Array,
     beta,
     *,
-    interpret: Optional[bool] = None,
+    interpret: bool,
 ):
-    # interpret=None auto-detects: compiled on TPU, interpreter elsewhere.
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n = y.shape[0]
     n_pad = -(-n // BLOCK) * BLOCK
 
@@ -106,8 +104,9 @@ def mrf_min_energy_pallas(
         # One element block per grid step, no output revisited — the
         # grid is safe to parallelize, and saying so lets Mosaic do it
         # (declared for the analysis race checker, DESIGN.md §15).
-        compiler_params=dict(mosaic=dict(dimension_semantics=("parallel",))),
+        compiler_params=tpu_compiler_params("parallel"),
         interpret=interpret,
+        name="mrf_min_energy",
     )(params, pad(y), pad(w), pad(n1_e), pad(nall_e), pad(xf))
 
     return min_e[:n], arg[:n]

@@ -23,6 +23,11 @@ lowerings plug in via :func:`register` without touching call sites.  (The
 pre-registry ``use_pallas=`` boolean went through its one-release
 deprecation window and has been removed; pass ``backend=`` or configure
 ``repro.api.ExecutionConfig(backend=...)``.)
+
+A size guard that sends work resolved to a Pallas backend to the XLA
+composition instead always says so with a :class:`BackendDowngradeWarning`
+(at trace time), whether the backend was named or auto-detected, so a run
+on the chip cannot silently measure the wrong implementation.
 """
 
 from __future__ import annotations
@@ -60,6 +65,17 @@ MAX_ONEHOT_BYTES = 8 * 1024 * 1024
 MAX_REDUCE_SEGMENTS = 4096
 
 _default_override: Optional[str] = None
+
+
+class BackendDowngradeWarning(UserWarning):
+    """Work resolved to a Pallas backend ran on the XLA composition because
+    a size guard refused the kernel."""
+
+
+class FusedKernelDowngradeWarning(BackendDowngradeWarning):
+    """The fused EM-tick / MAP-step kernels' VMEM guard refused the
+    kernel (:func:`_onehot_guard`)."""
+
 
 _REGISTRY: Dict[Tuple[str, str], Callable] = {}
 
@@ -104,16 +120,6 @@ def resolve_backend(backend: Optional[str] = None) -> str:
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; have {BACKENDS}")
     return backend
-
-
-def backend_explicitly_requested(backend: Optional[str]) -> bool:
-    """True when a pallas lowering was *asked for* rather than auto-detected
-    — via argument, env var, or process override.  Downgrade warnings fire
-    only for explicit requests; auto-detected fallbacks are the intended
-    routing and stay silent."""
-    if backend not in (None, "auto"):
-        return True
-    return bool(os.environ.get(ENV_VAR)) or _default_override is not None
 
 
 def _dispatch(op: str, backend: str) -> Callable:
@@ -189,6 +195,32 @@ def mrf_min_energy(
 # fused_map_step — the whole static-mode MAP iteration body in one launch
 # ---------------------------------------------------------------------------
 
+
+def _onehot_guard(op: str, backend: str, n_hoods: int, n_vertices: int) -> str:
+    """The fused kernels' VMEM guard: the backend to run ``op`` on.
+
+    Both fused kernels hold the hood and vertex one-hot tiles in VMEM at
+    once; past :data:`MAX_ONEHOT_BYTES` the op runs on the reference
+    composition (which still avoids the per-iteration sort and keeps the
+    hoisted reductions) and a :class:`FusedKernelDowngradeWarning` says so.
+    """
+    if backend == "xla":
+        return backend
+    pad = lambda s: -(-s // SEG_ALIGN) * SEG_ALIGN
+    onehot_bytes = (pad(n_hoods) + pad(n_vertices)) * MAP_STEP_BLOCK * 4
+    if onehot_bytes <= MAX_ONEHOT_BYTES:
+        return backend
+    warnings.warn(
+        f"{op}: one-hot tiles for (n_hoods={n_hoods}, "
+        f"n_vertices={n_vertices}) need {onehot_bytes/2**20:.1f} "
+        f"MB VMEM (> {MAX_ONEHOT_BYTES/2**20:.0f} MB); falling "
+        f"back from {backend!r} to the 'xla' composition",
+        FusedKernelDowngradeWarning,
+        stacklevel=3,
+    )
+    return "xla"
+
+
 register("fused_map_step", "xla")(ref.fused_map_step)
 
 
@@ -231,27 +263,9 @@ def fused_map_step(
     and ``mu``/``sigma`` are (K,); ``votes`` comes back (K, n_vertices)
     (DESIGN.md §13).
     """
-    requested = backend
-    backend = resolve_backend(backend)
-    if backend != "xla":
-        pad = lambda s: -(-s // SEG_ALIGN) * SEG_ALIGN
-        onehot_bytes = (pad(n_hoods) + pad(n_vertices)) * MAP_STEP_BLOCK * 4
-        if onehot_bytes > MAX_ONEHOT_BYTES:
-            # One-hot tiles would exceed VMEM; the reference composition
-            # still avoids the per-iteration sort and hoisted reductions.
-            # Surface the downgrade (at trace time) when the pallas backend
-            # was explicitly requested, so benchmarks/parity runs don't
-            # silently measure the wrong implementation; auto-detection
-            # falls back quietly (that IS the intended routing).
-            if backend_explicitly_requested(requested):
-                warnings.warn(
-                    f"fused_map_step: one-hot tiles for (n_hoods={n_hoods}, "
-                    f"n_vertices={n_vertices}) need {onehot_bytes/2**20:.1f} "
-                    f"MB VMEM (> {MAX_ONEHOT_BYTES/2**20:.0f} MB); falling "
-                    f"back from {backend!r} to the 'xla' composition",
-                    stacklevel=2,
-                )
-            backend = "xla"
+    backend = _onehot_guard(
+        "fused_map_step", resolve_backend(backend), n_hoods, n_vertices
+    )
     return _dispatch("fused_map_step", backend)(
         y, w, cnt_e, nall_e, xf, valid, hood_id, vertex, mu, sigma, beta,
         n_hoods=n_hoods, n_vertices=n_vertices,
@@ -313,26 +327,13 @@ def fused_em_tick(
     convergence.  Returns ``(labels, hood_e, votes, conv, sum_w, sum_wy,
     sum_wyy)`` (DESIGN.md §16).
 
-    Shares ``fused_map_step``'s VMEM guard: the kernel holds both one-hot
-    tiles at once, so oversized segment spaces fall back to the reference
-    composition (which still fuses at the XLA level — no per-tick sort,
-    one trace).
+    Shares ``fused_map_step``'s VMEM guard (:func:`_onehot_guard`):
+    oversized segment spaces run the reference composition (which still
+    fuses at the XLA level — no per-tick sort, one trace).
     """
-    requested = backend
-    backend = resolve_backend(backend)
-    if backend != "xla":
-        pad = lambda s: -(-s // SEG_ALIGN) * SEG_ALIGN
-        onehot_bytes = (pad(n_hoods) + pad(n_vertices)) * MAP_STEP_BLOCK * 4
-        if onehot_bytes > MAX_ONEHOT_BYTES:
-            if backend_explicitly_requested(requested):
-                warnings.warn(
-                    f"fused_em_tick: one-hot tiles for (n_hoods={n_hoods}, "
-                    f"n_vertices={n_vertices}) need {onehot_bytes/2**20:.1f} "
-                    f"MB VMEM (> {MAX_ONEHOT_BYTES/2**20:.0f} MB); falling "
-                    f"back from {backend!r} to the 'xla' composition",
-                    stacklevel=2,
-                )
-            backend = "xla"
+    backend = _onehot_guard(
+        "fused_em_tick", resolve_backend(backend), n_hoods, n_vertices
+    )
     return _dispatch("fused_em_tick", backend)(
         y, w, nall_e, xf, valid, hood_id, vertex, region_mean, region_weight,
         hist, mu, sigma, beta, n_hoods=n_hoods, n_vertices=n_vertices,

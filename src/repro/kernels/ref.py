@@ -148,8 +148,8 @@ def fused_em_tick(
     per-hood energy sums match ``fused_map_step``'s reference order, and
     the M-step sums match ``energy.update_parameters_stats``'s order —
     which is why this composition stays bitwise against the golden
-    fixtures while the kernel's dot-ordered M-sums may drift in final
-    ulps.  Returns ``(labels, hood_e, votes, conv, sum_w, sum_wy,
+    fixtures while the compiled kernel's dot-ordered M-sums may drift in
+    final ulps.  Returns ``(labels, hood_e, votes, conv, sum_w, sum_wy,
     sum_wyy)``.
     """
     from repro.kernels import em_tick as _em_tick
@@ -163,9 +163,12 @@ def fused_em_tick(
     cnt_e = counts[jnp.clip(hood_id, 0, n_hoods - 1)].T  # (K, H)
 
     energies = _em_tick.label_energies_blocked(
-        y, w, cnt_e, nall_e, xf, valid, mu, sigma, beta, precision=precision
+        y, w, cnt_e, nall_e, xf, valid, mu[:, None], sigma[:, None], beta,
+        precision=precision,
     )
-    min_e = jnp.min(energies, axis=0).astype(jnp.float32)
+    if precision == "bf16":
+        energies = _em_tick.round_to_bf16(energies)
+    min_e = jnp.min(energies, axis=0)
     arg = jnp.argmin(energies, axis=0).astype(jnp.int32)
 
     hood_e = jax.ops.segment_sum(

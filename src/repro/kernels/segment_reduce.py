@@ -20,13 +20,17 @@ a one-hot row, so callers pad values with anything and ids with 2**30.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK_SEG = 512   # BS: segment rows per tile (multiple of 128 for MXU)
+from repro.compat import tpu_compiler_params
+
+# BS: segment rows per tile.  XLA lays out a 1-D f32 array of 1024 or
+# more elements in T(1024) tiles, and Mosaic requires the output block to
+# match, so the segment axis is tiled (and padded) in 1024s.
+BLOCK_SEG = 1024
 BLOCK_VAL = 1024  # BN: value lanes per tile
 
 
@@ -74,15 +78,9 @@ def segment_reduce_pallas(
     num_segments: int,
     op: str = "add",
     *,
-    interpret: Optional[bool] = None,
+    interpret: bool,
 ) -> jax.Array:
-    """Segmented reduction via pl.pallas_call.  1D float values only.
-
-    ``interpret=None`` auto-detects: compiled on TPU, interpreter elsewhere.
-    Pass an explicit bool to force either (tests use ``interpret=True``).
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    """Segmented reduction via pl.pallas_call.  1D float values only."""
     n = values.shape[0]
     n_pad = -(-n // BLOCK_VAL) * BLOCK_VAL
     s_pad = -(-num_segments // BLOCK_SEG) * BLOCK_SEG
@@ -108,14 +106,8 @@ def segment_reduce_pallas(
         # blocks and is declared parallel.  Stated explicitly for the
         # analysis race checker (PL101/PL104, DESIGN.md §15) instead of
         # leaning on Mosaic's implicit sequential default.
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "arbitrary"))
-        ),
+        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name="segment_reduce",
     )(segs, vals)
-
-    out = out[:num_segments]
-    if op == "min":
-        # empty segments: match jax.ops.segment_min identity (max float)
-        return out
-    return out
+    return out[:num_segments]

@@ -89,6 +89,9 @@ def main() -> None:
         from repro.xla_env import force_host_device_count
 
         force_host_device_count(max_auto_shards if auto_shards else forced_shards)
+    from repro.xla_env import use_compile_cache
+
+    use_compile_cache()
 
     from repro import api
     from repro.core import metrics as M

@@ -43,6 +43,7 @@ from repro.core import synthetic
 from repro.serving import SegmentationEngine
 from repro.serving.engine import DEFAULT_TICK_LADDER
 from repro.testing import chaos as chaos_mod
+from repro.xla_env import use_compile_cache
 
 #: Fault classes --chaos cycles through (round-robin over the poisoned rids).
 CHAOS_CYCLE = ("bad_init", "nan_image", "never_converge", "nan_data")
@@ -114,6 +115,7 @@ def main() -> None:
         ap.error("--latency-gate must be >= 0")
     if args.latency_gate and not args.check:
         ap.error("--latency-gate requires --check")
+    use_compile_cache()
 
     cfg = api.ExecutionConfig(
         backend=args.backend, mode=args.mode,

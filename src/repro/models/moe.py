@@ -23,8 +23,6 @@ from __future__ import annotations
 from typing import Dict, Optional, Tuple
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
@@ -162,7 +160,7 @@ def moe_ffn(
         out = moe_ffn_local(p, x2d, cfg)
     else:
         idx = jax.lax.axis_index(axis)
-        n_shards = compat.axis_size(axis)
+        n_shards = jax.lax.axis_size(axis)
         e_loc = cfg.moe_num_experts // n_shards
         out = moe_ffn_local(
             p, x2d, cfg, expert_offset=idx * e_loc, n_local_experts=e_loc

@@ -18,8 +18,6 @@ from __future__ import annotations
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -158,7 +156,7 @@ def _ffn_apply(p, x, cfg: ModelConfig, rt: Optional[ParallelRuntime]):
                 },
                 P(dp, None, None),
             )
-            return compat.shard_map(
+            return jax.shard_map(
                 local_fn, mesh=mesh, in_specs=in_specs,
                 out_specs=P(dp, None, None), check_vma=False,
             )(moe_p, x)

@@ -24,8 +24,6 @@ from functools import partial
 from typing import Optional, Tuple
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -87,7 +85,7 @@ def sp_decode_attention(
     cspec = P(bs, None, seq_axis, None)
 
     @partial(
-        compat.shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(qspec, cspec, cspec, qspec, qspec, P()),
         out_specs=(qspec, cspec, cspec),
         check_vma=False,
@@ -147,7 +145,7 @@ def sp_decode_attention_mla(
     kspec = P(bs, None, seq_axis, None)
 
     @partial(
-        compat.shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(qspec, cspec, kspec, P(bs, None, None), qspec, P()),
         out_specs=(qspec, cspec, kspec),
         check_vma=False,

@@ -11,12 +11,14 @@ execution axes:
   2/4/8 on the paper-config slice stack: fits the lane-serialization
   fraction (how much of a vmapped batch's width the platform pays in
   wall clock — ~1 on XLA:CPU, ~0 on accelerators).
-* **sharded grid** — the BENCH_sharded size ladder at 1 and 8 shards in
-  a child process with 8 forced host devices (the XLA device count is
-  process-global, same pattern as ``benchmarks/bench_sharded.py``): fits
-  the per-MAP-iteration collective-overhead terms.  The child's 1-shard
-  rows double as solve observations so the sharded residuals are
-  computed against timings from the same process environment.
+* **sharded grid** — the BENCH_sharded size ladder at 1 and 8 shards:
+  fits the per-MAP-iteration collective-overhead terms.  On CPU it runs
+  in a child process with 8 forced host devices (the XLA device count is
+  process-global, same pattern as ``benchmarks/bench_sharded.py``); on an
+  accelerator this process already holds the chips, so it runs here, with
+  the shard counts capped at ``jax.device_count()``.  The 1-shard rows
+  double as solve observations so the sharded residuals are computed
+  against timings from the same process environment.
 
 Raw observations are stored *inside* the table, so the fit — and
 therefore the table bytes — is a pure function of the file's own
@@ -164,12 +166,12 @@ def _batched_obs(width: int) -> Dict:
     }
 
 
-def _sharded_child() -> List[Dict]:
-    """Runs inside the 8-device child: the BENCH_sharded ladder at 1 and
-    8 shards.  1-shard rows are plain solve observations."""
+def _sharded_ladder(shard_counts=SHARD_COUNTS) -> List[Dict]:
+    """The BENCH_sharded ladder at each shard count (in the 8-device child
+    on CPU).  1-shard rows are plain solve observations."""
     obs = []
     for size in SHARD_SIZES:
-        for shards in SHARD_COUNTS:
+        for shards in shard_counts:
             obs.append(_solve_obs(SHARD_MODE, size, 2, shards=shards))
     return obs
 
@@ -207,9 +209,17 @@ def collect_observations(*, sharded: bool = True) -> List[Dict]:
         obs.append(_batched_obs(width))
         print(f"  batched width={width}: {obs[-1]['seconds']}s", file=sys.stderr)
     if sharded:
-        obs.extend(_run_sharded_child())
-        print(f"  sharded ladder: {len(SHARD_SIZES) * len(SHARD_COUNTS)} points",
-              file=sys.stderr)
+        import jax
+
+        # One process per chip: only the CPU backend measures in a child.
+        if jax.default_backend() == "cpu":
+            ladder = _run_sharded_child()
+        else:
+            ladder = _sharded_ladder(
+                tuple(sorted({1, min(max(SHARD_COUNTS), jax.device_count())}))
+            )
+        obs.extend(ladder)
+        print(f"  sharded ladder: {len(ladder)} points", file=sys.stderr)
     return obs
 
 
@@ -267,7 +277,7 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     if args.sharded_child:
-        print(json.dumps(_sharded_child()))
+        print(json.dumps(_sharded_ladder()))
         return
 
     if args.refit:

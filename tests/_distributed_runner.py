@@ -15,7 +15,7 @@ assert "xla_force_host_platform_device_count" in os.environ.get("XLA_FLAGS", "")
 
 import jax
 
-from repro.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.compat import tpu_compiler_params
+
 _N = 128
 _BLOCK = 64
 
@@ -47,9 +49,7 @@ def racy_jaxpr():
             # every i; i is declared parallel -> race.
             out_specs=pl.BlockSpec((_BLOCK,), lambda i, j: (j,)),
             out_shape=jax.ShapeDtypeStruct((_N,), jnp.float32),
-            compiler_params=dict(
-                mosaic=dict(dimension_semantics=("parallel", "parallel"))
-            ),
+            compiler_params=tpu_compiler_params("parallel", "parallel"),
         )(x)
 
     return _trace(fn)
@@ -65,7 +65,7 @@ def oob_jaxpr():
             in_specs=[pl.BlockSpec((_BLOCK,), lambda i: (i,))],
             out_specs=pl.BlockSpec((_BLOCK,), lambda i: (i + 1,)),
             out_shape=jax.ShapeDtypeStruct((_N,), jnp.float32),
-            compiler_params=dict(mosaic=dict(dimension_semantics=("parallel",))),
+            compiler_params=tpu_compiler_params("parallel"),
         )(x)
 
     return _trace(fn)
@@ -81,7 +81,7 @@ def nondivisible_jaxpr():
             in_specs=[pl.BlockSpec((_BLOCK,), lambda i: (i,))],
             out_specs=pl.BlockSpec((_BLOCK,), lambda i: (i,)),
             out_shape=jax.ShapeDtypeStruct((96,), jnp.float32),
-            compiler_params=dict(mosaic=dict(dimension_semantics=("parallel",))),
+            compiler_params=tpu_compiler_params("parallel"),
         )(x)
 
     return _trace(fn)

@@ -18,9 +18,11 @@ import jax.numpy as jnp
 from repro.core import dpp
 
 # Small sizes: pallas-interpret runs each kernel through the interpreter,
-# and hypothesis multiplies examples — keep the product cheap.
+# and hypothesis multiplies examples — keep the product cheap.  No
+# subnormals: XLA:CPU flushes them to zero, which no numpy oracle does.
 _values = st.lists(
-    st.floats(-100, 100, allow_nan=False, width=32), min_size=1, max_size=48
+    st.floats(-100, 100, allow_nan=False, allow_subnormal=False, width=32),
+    min_size=1, max_size=48,
 )
 _n_segments = st.integers(min_value=1, max_value=12)
 

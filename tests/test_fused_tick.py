@@ -107,11 +107,11 @@ def test_fused_em_tick_dispatch_and_vmem_fallback():
     got = kops.fused_em_tick(*args, 0.75, backend="pallas-interpret", **kw)
     _compare(want, got, hood_e_bitwise=False)
     # Over the one-hot VMEM ceiling the wrapper falls back to the xla
-    # composition (warning only because the backend was explicit), and the
-    # result still matches the reference bitwise — it IS the reference.
+    # composition (with a FusedKernelDowngradeWarning), and the result
+    # still matches the reference bitwise — it IS the reference.
     big_h, big_v = 1500, 700  # padded tiles: (1536+768)*BLOCK*4 B > 8 MB
     big_args = _random_tick_problem(1, 2, big_h, big_v, 2000)
-    with pytest.warns(UserWarning, match="falling back"):
+    with pytest.warns(kops.FusedKernelDowngradeWarning, match="falling back"):
         got_big = kops.fused_em_tick(
             *big_args, 0.75, backend="pallas-interpret",
             n_hoods=big_h, n_vertices=big_v,
@@ -120,6 +120,31 @@ def test_fused_em_tick_dispatch_and_vmem_fallback():
         *big_args, 0.75, n_hoods=big_h, n_vertices=big_v
     )
     _compare(want_big, got_big, hood_e_bitwise=True)
+
+
+@pytest.mark.parametrize("op", ["fused_em_tick", "reduce_by_key"])
+def test_size_guard_warns_when_auto_resolves_to_pallas(monkeypatch, op):
+    # On a TPU "auto" resolves to pallas-tpu; a guard that then keeps the
+    # work on XLA must say so exactly as for a named backend.
+    from repro.core import dpp
+
+    monkeypatch.delenv(kops.ENV_VAR, raising=False)
+    monkeypatch.setattr(kops, "_auto_backend", lambda: "pallas-interpret")
+    want = {
+        "fused_em_tick": kops.FusedKernelDowngradeWarning,
+        "reduce_by_key": kops.BackendDowngradeWarning,
+    }[op]
+    with pytest.warns(want, match=op):
+        if op == "reduce_by_key":
+            dpp.reduce_by_key(
+                jnp.zeros((8,), jnp.int32), jnp.ones((8,), jnp.float32),
+                kops.MAX_REDUCE_SEGMENTS + 1, backend="auto",
+            )
+        else:
+            kops.fused_em_tick(
+                *_random_tick_problem(1, 2, 1500, 700, 2000), 0.75,
+                n_hoods=1500, n_vertices=700, backend="auto",
+            )
 
 
 # ---------------------------------------------------------------------------

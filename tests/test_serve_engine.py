@@ -201,6 +201,9 @@ def test_adaptive_tick_cache_per_size_no_retrace_no_alias():
     hit the LRU warm (zero new traces — regardless of how many switches
     happen), distinct sizes get distinct cache keys (never aliased), and
     results stay bitwise serial-identical under any tick-size schedule."""
+    # The trace counts below assume a cold jit cache: another test file in
+    # the same process may already have traced these ticked programs.
+    jax.clear_caches()
     sess = _session()
     plans = _mixed_plans(sess, n=5)
     serial = [sess.execute(p, seed=0) for p in plans]

@@ -70,7 +70,9 @@ def test_lr_schedule_shape():
 
 
 @settings(max_examples=20, deadline=None)
-@given(st.lists(st.floats(-100, 100, allow_nan=False, width=32),
+# No subnormals: XLA:CPU flushes them to zero, which numpy does not.
+@given(st.lists(st.floats(-100, 100, allow_nan=False, allow_subnormal=False,
+                          width=32),
                 min_size=1, max_size=16))
 def test_clip_by_global_norm_bound(xs):
     g = {"x": jnp.asarray(xs, jnp.float32)}
