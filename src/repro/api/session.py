@@ -27,6 +27,7 @@ performance layer, never a semantics layer.
 
 from __future__ import annotations
 
+import itertools
 import time
 import warnings
 from collections import OrderedDict
@@ -38,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import obs
 from repro.analysis import budget as budget_mod
 from repro.api.config import ExecutionConfig
 from repro.api.errors import FallbackError, PlanError
@@ -50,6 +52,9 @@ from repro.core.pmrf.hoods import Hoods, pad_hoods
 from repro.testing import chaos as chaos_mod
 
 Array = jax.Array
+
+#: Plan ids: the request id the session's spans carry.
+_PLAN_IDS = itertools.count()
 
 
 class BucketKey(NamedTuple):
@@ -97,7 +102,10 @@ class ExecutableKey(NamedTuple):
 
 @dataclass
 class Plan:
-    """A planned (initialized + bucketed) segmentation problem."""
+    """A planned (initialized + bucketed) segmentation problem.
+
+    ``init_seconds`` is the ``plan`` span less its ``plan.cost`` child: the
+    plan's own work, without the cost model's prediction."""
 
     problem: pipeline_mod.Problem
     bucket: BucketKey
@@ -106,6 +114,8 @@ class Plan:
     # plan under the session's config — what the autotuner compares when
     # routing, surfaced here so callers can budget before executing.
     predicted_optimize_s: Optional[float] = None
+    # The request id this plan's spans carry (``repro.obs``).
+    id: int = field(default_factory=lambda: next(_PLAN_IDS), compare=False)
     # Padded-input memo keyed by (bucket, seed, init): repeat executes of
     # the same plan are pure device replays, not re-pads (see _pad_plan).
     _padded: dict = field(default_factory=dict, repr=False, compare=False)
@@ -117,16 +127,17 @@ class Plan:
 
 @dataclass
 class Executable:
-    """One AOT-compiled EM program for a bucket (and optional batch size)."""
+    """One AOT-compiled EM program for a bucket (and optional batch size).
+
+    ``compile_seconds`` is the ``session.compile`` span of the miss that
+    built it."""
 
     key: ExecutableKey
     compiled: object                 # jax.stages.Compiled
     em_config: em_mod.EMConfig
     compile_seconds: float
-    calls: int = 0
 
     def __call__(self, *inputs):
-        self.calls += 1
         return self.compiled(*inputs)
 
 
@@ -273,41 +284,43 @@ class Segmenter:
         otherwise flow silently into the region statistics and poison the
         lane's first energy evaluation.
         """
-        t0 = time.perf_counter()
-        img = np.asarray(image)
-        if img.size == 0:
-            raise PlanError(f"cannot plan a zero-element image (shape {img.shape})")
-        if np.issubdtype(img.dtype, np.floating) and not np.isfinite(img).all():
-            bad = int(np.size(img) - np.isfinite(img).sum())
-            raise PlanError(
-                f"image contains {bad} non-finite pixel(s); segmentation "
-                "energies are undefined for NaN/Inf intensities"
+        plan_id = next(_PLAN_IDS)
+        with obs.span("plan", req=plan_id) as whole:
+            img = np.asarray(image)
+            if img.size == 0:
+                raise PlanError(f"cannot plan a zero-element image (shape {img.shape})")
+            if np.issubdtype(img.dtype, np.floating) and not np.isfinite(img).all():
+                bad = int(np.size(img) - np.isfinite(img).sum())
+                raise PlanError(
+                    f"image contains {bad} non-finite pixel(s); segmentation "
+                    "energies are undefined for NaN/Inf intensities"
+                )
+            problem = pipeline_mod.initialize(
+                img,
+                overseg_grid=self.config.overseg_grid,
+                overseg_iters=self.config.overseg_iters,
+                beta=self.config.beta,
+                sigma_min=self.config.sigma_min,
+                n_labels=self.config.n_labels,
+                oversegmentation=oversegmentation,
             )
-        image = img
-        problem = pipeline_mod.initialize(
-            image,
-            overseg_grid=self.config.overseg_grid,
-            overseg_iters=self.config.overseg_iters,
-            beta=self.config.beta,
-            sigma_min=self.config.sigma_min,
-            n_labels=self.config.n_labels,
-            oversegmentation=oversegmentation,
-        )
-        init_s = time.perf_counter() - t0
-        bucket = self.bucket_of(problem.hoods)
+            bucket = self.bucket_of(problem.hoods)
+            with obs.span("plan.cost") as cost:
+                predicted = self.cost_model().predict_solve(
+                    mode=self.config.mode,
+                    bucket=bucket,
+                    n_labels=self.config.n_labels,
+                    shards=self.config.shards,
+                    precision=self.config.precision,
+                    max_em_iters=self.config.max_em_iters,
+                    max_map_iters=self.config.max_map_iters,
+                )
         return Plan(
             problem=problem,
             bucket=bucket,
-            init_seconds=init_s,
-            predicted_optimize_s=self.cost_model().predict_solve(
-                mode=self.config.mode,
-                bucket=bucket,
-                n_labels=self.config.n_labels,
-                shards=self.config.shards,
-                precision=self.config.precision,
-                max_em_iters=self.config.max_em_iters,
-                max_map_iters=self.config.max_map_iters,
-            ),
+            init_seconds=whole.seconds - cost.seconds,
+            predicted_optimize_s=predicted,
+            id=plan_id,
         )
 
     def cost_model(self) -> planning_mod.CostModel:
@@ -394,23 +407,23 @@ class Segmenter:
         resolved backend), with a redirect recorded so warm traffic for
         the original key lands on the fallback executable directly.
         """
-        key = self._fallback_redirects.get(key, key)
-        exe = self._cache.get(key)
-        if exe is not None:
-            self._cache.move_to_end(key)
-            self.stats.hits += 1
-            budget_mod.LEDGER.bump("compile", "warm_hit")
-            return exe
+        with obs.span("session.compile") as lookup:
+            key = self._fallback_redirects.get(key, key)
+            exe = self._cache.get(key)
+            if exe is not None:
+                self._cache.move_to_end(key)
+                self.stats.hits += 1
+                budget_mod.LEDGER.bump("compile", "warm_hit")
+                return exe
 
-        self.stats.misses += 1
-        budget_mod.LEDGER.bump("compile", "lower_compile")
-        t0 = time.perf_counter()
-        compiled, em_config, used_key = self._build_with_policy(key, build)
+            self.stats.misses += 1
+            budget_mod.LEDGER.bump("compile", "lower_compile")
+            compiled, em_config, used_key = self._build_with_policy(key, build)
         exe = Executable(
             key=used_key,
             compiled=compiled,
             em_config=em_config,
-            compile_seconds=time.perf_counter() - t0,
+            compile_seconds=lookup.seconds,
         )
         self._cache[used_key] = exe
         while len(self._cache) > self.config.max_cached_executables:
@@ -721,14 +734,31 @@ class Segmenter:
         executable, then one recompile+rerun on the fallback backend (the
         redirect is remembered, so subsequent traffic goes straight to
         the fallback executable).
+
+        Spans, under ``session.execute`` (tagged with the plan's id):
+        ``session.compile``, ``session.pad``, ``session.launch`` (until the
+        asynchronous call returns), ``session.wait`` and
+        ``session.assemble``; ``optimize_seconds`` is launch plus wait.
         """
         bucket = BucketKey(*bucket) if bucket is not None else plan.bucket
-        exe = self.compile(bucket)
-        inputs = self._pad_plan(plan, bucket, seed)
+        with obs.span("session.execute", req=plan.id):
+            exe = self.compile(bucket)
+            with obs.span("session.pad"):
+                inputs = self._pad_plan(plan, bucket, seed)
+            with obs.span("session.launch") as launch:
+                res = self._launch(exe, bucket, inputs)
+            with obs.span("session.wait") as wait:
+                jax.block_until_ready(res.labels)
+            with obs.span("session.assemble"):
+                return pipeline_mod._assemble_result(
+                    plan.problem, res, plan.init_seconds, launch.seconds + wait.seconds
+                )
+
+    def _launch(self, exe: Executable, bucket: BucketKey, inputs):
+        """Call ``exe`` under the fallback policy (see :meth:`execute`)."""
         policy = self.config.fallback
-        t0 = time.perf_counter()
         try:
-            res = self._run_with_retry(exe, inputs)
+            return self._run_with_retry(exe, inputs)
         except Exception as e:
             if not (policy.enabled and exe.key.backend != policy.backend):
                 raise
@@ -744,22 +774,19 @@ class Segmenter:
                 f"execute on backend {exe.key.backend!r} failed ({e!r}); "
                 f"retrying on fallback backend {policy.backend!r}",
                 RuntimeWarning,
-                stacklevel=2,
+                stacklevel=3,
             )
             self._fallback_redirects[exe.key] = exe.key._replace(
                 backend=policy.backend
             )
             exe = self.compile(bucket, backend=policy.backend)
             try:
-                res = self._run_with_retry(exe, inputs)
+                return self._run_with_retry(exe, inputs)
             except Exception as fb_e:
                 raise FallbackError(
                     f"execute failed on {self.config.resolved_backend()!r} "
                     f"and on the fallback backend {policy.backend!r}"
                 ) from fb_e
-        jax.block_until_ready(res.labels)
-        opt_s = time.perf_counter() - t0
-        return pipeline_mod._assemble_result(plan.problem, res, plan.init_seconds, opt_s)
 
     def segment(self, image, *, seed: int = 0, oversegmentation=None):
         """Convenience: plan + execute in one call."""
@@ -826,21 +853,27 @@ class Segmenter:
                             pending[i].plan, seed=pending[i].seed, bucket=bucket
                         )
                     continue
-                exe = self.compile(bucket, batch=len(members))
-                padded = [
-                    self._pad_plan(pending[i].plan, bucket, pending[i].seed)
-                    for i in members
-                ]
-                stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *padded)
-                t0 = time.perf_counter()
-                res = exe(*stacked)
-                jax.block_until_ready(res.labels)
-                opt_s = (time.perf_counter() - t0) / len(members)
-                for j, i in enumerate(members):
-                    res_i = em_mod.EMResult(*(leaf[j] for leaf in res))
-                    results[i] = pipeline_mod._assemble_result(
-                        pending[i].plan.problem, res_i, pending[i].plan.init_seconds, opt_s
-                    )
+                ids = tuple(pending[i].plan.id for i in members)
+                with obs.span("session.execute", req=ids):
+                    exe = self.compile(bucket, batch=len(members))
+                    with obs.span("session.pad"):
+                        padded = [
+                            self._pad_plan(pending[i].plan, bucket, pending[i].seed)
+                            for i in members
+                        ]
+                        stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *padded)
+                    with obs.span("session.launch") as launch:
+                        res = exe(*stacked)
+                    with obs.span("session.wait") as wait:
+                        jax.block_until_ready(res.labels)
+                    opt_s = (launch.seconds + wait.seconds) / len(members)
+                    with obs.span("session.assemble"):
+                        for j, i in enumerate(members):
+                            res_i = em_mod.EMResult(*(leaf[j] for leaf in res))
+                            results[i] = pipeline_mod._assemble_result(
+                                pending[i].plan.problem, res_i,
+                                pending[i].plan.init_seconds, opt_s,
+                            )
         except Exception:
             # One group failing (compile OOM, bad bucket override) must not
             # strand the others: re-queue every request that has no result

@@ -17,17 +17,16 @@ Two semantic adaptations vs. the VTK-m originals (see DESIGN.md §2):
   known.  ``sort_by_key`` is still provided (bitonic via ``lax.sort``) for
   the paper-faithful execution mode.
 
-Every primitive optionally records an invocation event into the active
-:class:`DppProfile` so the per-primitive breakdown of the paper's §4.3.2 can
-be reproduced (``benchmarks/bench_fig4.py``).
+Inside :func:`profiled` every primitive runs eagerly to completion as a
+``dpp.<Primitive>`` span (``repro.obs``), so the per-primitive breakdown of
+the paper's §4.3.2 can be reproduced (``benchmarks/bench_fig4.py``).
 """
 
 from __future__ import annotations
 
 import contextlib
+import math
 import threading
-import time
-from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -35,28 +34,37 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 Array = jax.Array
 
 # ---------------------------------------------------------------------------
 # Profiling (per-DPP breakdown, paper §4.3.2)
 # ---------------------------------------------------------------------------
 
-_tls = threading.local()
+_views = 0  # open profiled() views, changed under _views_lock
+_views_lock = threading.Lock()
 
 
-@dataclass
 class DppProfile:
-    """Accumulates per-primitive wall times (eager mode only).
+    """Per-primitive wall times: a view over the ``dpp.<Primitive>`` spans
+    of one ``repro.obs`` recording (eager mode only).
 
-    Inside ``jit`` the events fuse away; the profiler is intended for the
+    Inside ``jit`` the primitives fuse away; the view is intended for the
     benchmark harness, which runs the pipeline eagerly to reproduce the
     paper's per-DPP timing analysis.
     """
 
-    events: Dict[str, List[float]] = field(default_factory=dict)
+    def __init__(self, recorder: obs.Recorder):
+        self._recorder = recorder
 
-    def record(self, name: str, seconds: float) -> None:
-        self.events.setdefault(name, []).append(seconds)
+    @property
+    def events(self) -> Dict[str, List[float]]:
+        out: Dict[str, List[float]] = {}
+        for r in self._recorder.records:
+            if r.name.startswith("dpp.") and not math.isnan(r.end):
+                out.setdefault(r.name[len("dpp."):], []).append(r.seconds)
+        return out
 
     def totals(self) -> Dict[str, float]:
         return {k: float(sum(v)) for k, v in self.events.items()}
@@ -68,28 +76,24 @@ class DppProfile:
 @contextlib.contextmanager
 def profiled():
     """Context manager enabling per-DPP timing; yields the profile."""
-    prof = DppProfile()
-    prev = getattr(_tls, "profile", None)
-    _tls.profile = prof
-    try:
-        yield prof
-    finally:
-        _tls.profile = prev
-
-
-def _active_profile() -> Optional[DppProfile]:
-    return getattr(_tls, "profile", None)
+    global _views
+    with obs.recording() as rec:
+        with _views_lock:
+            _views += 1
+        try:
+            yield DppProfile(rec)
+        finally:
+            with _views_lock:
+                _views -= 1
 
 
 def _timed(name: str, fn: Callable[[], Any]) -> Any:
-    prof = _active_profile()
-    if prof is None:
+    if not _views:
         return fn()
     # Eager timing: block on result so the measurement is honest.
-    t0 = time.perf_counter()
-    out = fn()
-    jax.block_until_ready(out)
-    prof.record(name, time.perf_counter() - t0)
+    with obs.span(f"dpp.{name}"):
+        out = fn()
+        jax.block_until_ready(out)
     return out
 
 

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import oversegment
 from repro.core.pmrf import em as em_mod
 from repro.core.pmrf.cliques import CliqueSet, enumerate_maximal_cliques
@@ -70,29 +71,39 @@ def initialize(
     oversegmentation=None,
 ) -> Problem:
     """Initialization phase (paper Alg. 2 lines 1-5): graph + cliques +
-    neighborhoods.  Untimed in the paper's methodology but fully built.
+    neighborhoods.  Untimed in the paper's methodology but fully built;
+    each stage is a span (``plan.slic``, ``plan.graph``, ``plan.cliques``,
+    ``plan.hoods``, ``plan.model``).
     ``n_labels`` sizes the model's label axis (K-ary segmentation,
     DESIGN.md §13); the graph/clique/hood structure is label-free."""
-    img = jnp.asarray(image, jnp.float32)
-    if oversegmentation is None:
-        labels_px = oversegment.slic(img, grid=overseg_grid, iters=overseg_iters)
-        n_regions = overseg_grid[0] * overseg_grid[1]
-    else:
-        labels_px = jnp.asarray(oversegmentation, jnp.int32)
-        n_regions = int(np.asarray(labels_px).max()) + 1
-    graph = build_region_graph(img, labels_px, n_regions)
-    cliques = enumerate_maximal_cliques(graph)
-    hoods = build_hoods(graph, cliques)
-    model = make_energy_model(
-        graph.region_mean, graph.region_size, beta=beta, sigma_min=sigma_min,
-        n_labels=n_labels,
-    )
+    with obs.span("plan.slic"):
+        img = jnp.asarray(image, jnp.float32)
+        if oversegmentation is None:
+            labels_px = oversegment.slic(img, grid=overseg_grid, iters=overseg_iters)
+            n_regions = overseg_grid[0] * overseg_grid[1]
+        else:
+            labels_px = jnp.asarray(oversegmentation, jnp.int32)
+            n_regions = int(np.asarray(labels_px).max()) + 1
+        # The graph reads the label map on the host: the read (and so the
+        # wait for SLIC on the device) belongs to this stage.
+        labels_host = np.asarray(labels_px)
+    with obs.span("plan.graph"):
+        graph = build_region_graph(img, labels_px, n_regions)
+    with obs.span("plan.cliques"):
+        cliques = enumerate_maximal_cliques(graph)
+    with obs.span("plan.hoods"):
+        hoods = build_hoods(graph, cliques)
+    with obs.span("plan.model"):
+        model = make_energy_model(
+            graph.region_mean, graph.region_size, beta=beta, sigma_min=sigma_min,
+            n_labels=n_labels,
+        )
     return Problem(
         graph=graph,
         cliques=cliques,
         hoods=hoods,
         model=model,
-        labels_px=np.asarray(labels_px),
+        labels_px=labels_host,
     )
 
 

@@ -22,11 +22,13 @@ Usage::
         --mode static --backend auto --repeat 3 --dataset synthetic
     PYTHONPATH=src python -m repro.launch.segment --shards 8 --mode static
     PYTHONPATH=src python -m repro.launch.segment --shards auto  # cost model picks
+    PYTHONPATH=src python -m repro.launch.segment --spans  # per-span table on stderr
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 import time
@@ -76,6 +78,12 @@ def main() -> None:
                     default="synthetic")
     ap.add_argument("--init", choices=("random", "quantile"), default="quantile")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument(
+        "--spans", action="store_true",
+        help="record the program's spans (repro.obs) and print, to stderr, "
+        "one row per span name: count, total and self seconds, and the XLA "
+        "compiles that ran in it; then the growth of the LEDGER counters",
+    )
     args = ap.parse_args()
 
     auto_shards = args.shards == "auto"
@@ -93,6 +101,15 @@ def main() -> None:
 
     use_compile_cache()
 
+    from repro import obs
+
+    with obs.recording() if args.spans else contextlib.nullcontext() as rec:
+        _run(ap, args, auto_shards, forced_shards)
+    if rec is not None:
+        print(rec.format_table(), file=sys.stderr)
+
+
+def _run(ap, args, auto_shards, forced_shards) -> None:
     from repro import api
     from repro.core import metrics as M
     from repro.core import synthetic as S

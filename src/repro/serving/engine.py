@@ -69,6 +69,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import numpy as np
 
+from repro import obs
 from repro.analysis import budget as budget_mod
 from repro.api.config import ExecutionConfig
 from repro.api.errors import FallbackError, RequestError
@@ -317,7 +318,8 @@ class SegmentationEngine:
         self.steps_saved = 0           # tick_iters - steps (early tick exits)
         self.tick_switches: List[Tuple[int, int, int]] = []  # (tick, from, to)
         self.fallback_events: List[Dict] = []
-        # Per-tick cost instrumentation (DESIGN.md §17): host-phase timers
+        # Per-tick cost instrumentation (DESIGN.md §17): host-phase timers,
+        # each read off its span (serve.admit/advance/sync/retire, repro.obs),
         # plus a decayed least-squares fit of cost(t) = a + b*t over
         # (steps_executed, tick_duration) observations.
         self._phase_s = {"admit": 0.0, "advance": 0.0, "sync": 0.0, "retire": 0.0}
@@ -776,28 +778,28 @@ class SegmentationEngine:
         pool is done), retire finished/quarantined lanes, evict
         overstayers, then let the adaptive policy reconsider the tick
         size.  Returns the number of lanes advanced (0 = nothing to do)."""
-        t_admit = time.perf_counter()
-        if self._heap:
-            self._ensure_pool()
-            self._admit()
-        n_active = self.active()
+        with obs.span("serve.admit") as admit:
+            if self._heap:
+                self._ensure_pool()
+                self._admit()
+            n_active = self.active()
         if n_active == 0:
             return 0
-        self._phase_s["admit"] += time.perf_counter() - t_admit
-        t0 = time.perf_counter()
-        chaos_mod.on_tick(self.ticks)
-        self._state, steps_dev = self._advance_pool()
-        t1 = time.perf_counter()
-        self._phase_s["advance"] += t1 - t0
+        self._phase_s["admit"] += admit.seconds
+        with obs.span("serve.advance") as advance:
+            chaos_mod.on_tick(self.ticks)
+            self._state, steps_dev = self._advance_pool()
+        self._phase_s["advance"] += advance.seconds
         # THE per-tick sync point: one host fetch for the done vector and
         # the executed-step count together.
-        done, steps = jax.device_get((self._state.done, steps_dev))
-        done = np.array(done)   # writable copy: chaos holds flip entries
-        steps = int(steps)
-        t2 = time.perf_counter()
-        self._phase_s["sync"] += t2 - t1
-        self.watchdog.observe(self.ticks, t2 - t0)
-        self._record_tick(steps, t2 - t0)
+        with obs.span("serve.sync") as sync:
+            done, steps = jax.device_get((self._state.done, steps_dev))
+            done = np.array(done)   # writable copy: chaos holds flip entries
+            steps = int(steps)
+        self._phase_s["sync"] += sync.seconds
+        tick_s = sync.end - advance.start
+        self.watchdog.observe(self.ticks, tick_s)
+        self._record_tick(steps, tick_s)
         self.ticks += 1
         self.total_steps += steps
         self.lane_steps += n_active * steps
@@ -806,21 +808,21 @@ class SegmentationEngine:
         # sentinel sees serving activity alongside trace/compile events.
         budget_mod.LEDGER.bump("serve", "ticks")
         budget_mod.LEDGER.bump("serve", "lane_steps", n_active * steps)
-        t3 = time.perf_counter()
-        # Chaos never-converge holds: reset held lanes' progress before
-        # retirement so they can only leave via eviction.  Slot-local
-        # writes — co-resident lanes stay bitwise untouched.
-        for slot in range(self.max_batch):
-            if self._slot_hold[slot] and self.slot_req[slot] is not None:
-                req = self.slot_req[slot]
-                dmu = chaos_mod.monkey().hold_perturbation(
-                    req.rid, self.ticks, int(np.asarray(self._state.mu).shape[1])
-                )
-                self._state = _hold_lane_op(self._state, slot, dmu)
-                done[slot] = False
-        self._retire(done)
-        self._evict_overstayers()
-        self._phase_s["retire"] += time.perf_counter() - t3
+        with obs.span("serve.retire") as retire:
+            # Chaos never-converge holds: reset held lanes' progress before
+            # retirement so they can only leave via eviction.  Slot-local
+            # writes — co-resident lanes stay bitwise untouched.
+            for slot in range(self.max_batch):
+                if self._slot_hold[slot] and self.slot_req[slot] is not None:
+                    req = self.slot_req[slot]
+                    dmu = chaos_mod.monkey().hold_perturbation(
+                        req.rid, self.ticks, int(np.asarray(self._state.mu).shape[1])
+                    )
+                    self._state = _hold_lane_op(self._state, slot, dmu)
+                    done[slot] = False
+            self._retire(done)
+            self._evict_overstayers()
+        self._phase_s["retire"] += retire.seconds
         self._maybe_resize_tick()
         return n_active
 
