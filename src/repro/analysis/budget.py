@@ -14,6 +14,8 @@ section    who writes it
 "compile"  ``api.session`` records every ``lower().compile()``
            (``lower_compile``) and every warm LRU hit (``warm_hit``)
 "serve"    the serving engine records ``ticks`` and ``lane_steps``
+"plan"     ``hoods.build_hoods`` records ``hood_class_miss`` (the first
+           build of a shape class in the process) and ``hood_class_hit``
 =========  ==========================================================
 
 On top of the ledger sit *declared phase budgets*: the zero-retrace /

@@ -29,15 +29,8 @@ from dataclasses import dataclass, replace
 from typing import Tuple
 
 from repro.core.pmrf import em as em_mod
+from repro.core.pmrf.hoods import DEFAULT_CAPACITY_BUCKET, DEFAULT_SEGMENT_BUCKET
 from repro.kernels import ops as kops
-
-#: Granularity the padded neighborhood capacity is rounded up to.  Coarse
-#: buckets mean slightly different problems share one compiled executable
-#: (every static dim feeds the Hoods treedef, so an exact max would
-#: recompile on a one-element difference).
-DEFAULT_CAPACITY_BUCKET = 256
-#: Granularity for the n_hoods / n_regions static dims.
-DEFAULT_SEGMENT_BUCKET = 64
 
 
 @dataclass(frozen=True)
@@ -131,6 +124,8 @@ class ExecutionConfig:
     overseg_iters: int = 5
 
     # --- bucketing / caching -------------------------------------------
+    # One grid for the EM executables' buckets and the plan's hood program,
+    # which compiles once per shape class on it (DESIGN.md §2).
     capacity_bucket: int = DEFAULT_CAPACITY_BUCKET
     segment_bucket: int = DEFAULT_SEGMENT_BUCKET
     max_cached_executables: int = 32

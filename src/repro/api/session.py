@@ -303,6 +303,8 @@ class Segmenter:
                 sigma_min=self.config.sigma_min,
                 n_labels=self.config.n_labels,
                 oversegmentation=oversegmentation,
+                capacity_bucket=self.config.capacity_bucket,
+                segment_bucket=self.config.segment_bucket,
             )
             bucket = self.bucket_of(problem.hoods)
             with obs.span("plan.cost") as cost:

@@ -6,8 +6,10 @@ layer:
   1. **Find Neighbors** (Map): per clique-member slot, count 1-hop
      neighbors that are not members of the slot's clique.
   2. **Count Neighbors** (Scan): prefix-sum the counts to allocate the
-     neighborhoods array (static capacity computed host-side — the XLA
-     static-shape adaptation, DESIGN.md §2).
+     neighborhoods array.  The capacity is counted on the host and rounded
+     up on the session's bucket grid, so the program compiles once per
+     shape class, not per slice; the natural prefix is trimmed on the host
+     (the XLA static-shape adaptation, DESIGN.md §2).
   3. **Get Neighbors** (Map): populate candidate (cliqueId, vertexId)
      elements via the expand idiom (Scatter + max-Scan + Gather).
   4. **Remove Duplicate Neighbors** (SortByKey + Unique): sort candidates
@@ -27,9 +29,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import budget as budget_mod
 from repro.core import dpp
 from repro.core.pmrf.cliques import CliqueSet
 from repro.core.pmrf.graph import RegionGraph
+
+#: Granularity the padded neighborhood capacity is rounded up to.  Coarse
+#: buckets mean slightly different problems share one compiled executable
+#: (every static dim feeds the Hoods treedef, so an exact max would
+#: recompile on a one-element difference).
+DEFAULT_CAPACITY_BUCKET = 256
+#: Granularity for the n_hoods / n_regions static dims.
+DEFAULT_SEGMENT_BUCKET = 64
+
+# Shape classes :func:`build_hoods` has run in this process; the first run
+# of a class counts a ``plan.hood_class_miss``, every later one a hit.
+_SEEN_CLASSES: set = set()
 
 
 @jax.tree_util.register_dataclass
@@ -61,37 +76,76 @@ class Hoods:
         return int(self.vertex.shape[0])
 
 
-def build_hoods(graph: RegionGraph, cliques: CliqueSet) -> Hoods:
+def build_hoods(
+    graph: RegionGraph,
+    cliques: CliqueSet,
+    *,
+    capacity_bucket: int = DEFAULT_CAPACITY_BUCKET,
+    segment_bucket: int = DEFAULT_SEGMENT_BUCKET,
+) -> Hoods:
+    """The k=1 neighbourhoods of ``cliques`` in ``graph``.
+
+    The device work compiles at class shapes: the slice's counts rounded
+    up on the session's bucket grid (``capacity_bucket`` for lanes,
+    ``segment_bucket`` for clique rows), so slices whose natural shapes
+    differ share one program.  The natural prefix is read back and kept on
+    the host; the result is the same as a build at the natural shapes.
+    """
     n = graph.n_regions
     c = cliques.n_cliques
     if c == 0:
         raise ValueError("no cliques — empty graph?")
 
-    # Step 2's static capacity, counted on the host from the graph: one
-    # candidate per (clique member, 1-hop neighbor) pair.
+    # Step 2's capacity, counted on the host from the graph: one candidate
+    # per (clique member, 1-hop neighbor) pair; with the member lanes it
+    # gives the natural lane count.
     members = cliques.members
+    w = cliques.width
     deg = np.diff(graph.csr_offsets)
     neighbor_capacity = int(deg[members[members >= 0]].sum())
+    h_nat = neighbor_capacity + c * w
 
-    # Every shape below depends on the slice, so the device work is one
-    # jitted program (one compile per slice shape) rather than dozens of
-    # eagerly dispatched ops that each compile per shape.
-    vertex, hood_id, valid, sizes, hood_offsets, rep = _hood_arrays(
-        jnp.asarray(members),
+    # Class shapes: clique rows padded with -1 (no member, so no key), the
+    # CSR neighbour array and the key lanes rounded up on the bucket grid.
+    c_pad = _round_up(c, segment_bucket)
+    nnz = graph.csr_neighbors.shape[0]
+    members_pad = np.full((c_pad, w), -1, members.dtype)
+    members_pad[:c] = members
+    neighbors_pad = np.zeros(_round_up(max(nnz, 1), capacity_bucket),
+                             graph.csr_neighbors.dtype)
+    neighbors_pad[:nnz] = graph.csr_neighbors
+    n_lanes = _round_up(neighbor_capacity + c_pad * w, capacity_bucket)
+    shape_class = (c_pad, w, neighbors_pad.shape[0], n, n_lanes)
+    hit = shape_class in _SEEN_CLASSES
+    _SEEN_CLASSES.add(shape_class)
+    budget_mod.LEDGER.bump("plan", "hood_class_hit" if hit else "hood_class_miss")
+
+    out = _hood_arrays(
+        jnp.asarray(members_pad),
         jnp.asarray(graph.csr_offsets),
-        jnp.asarray(graph.csr_neighbors),
+        jnp.asarray(neighbors_pad),
+        np.int32(c),
+        np.int32(h_nat),
         n_regions=n,
-        neighbor_capacity=neighbor_capacity,
+        n_lanes=n_lanes,
     )
+    # Trim to the natural prefix on the host: a device slice at the
+    # slice's own sizes would compile per slice again.
+    vertex, hood_id, valid, sizes, offsets, rep = jax.device_get(out)
+    n_elements = int(valid[:h_nat].sum())
+    vertex, hood_id, valid, sizes, offsets, rep = jax.device_put((
+        vertex[:h_nat], hood_id[:h_nat], valid[:h_nat], sizes[:c],
+        offsets[: c + 1], tuple(r[: 2 * h_nat] for r in rep),
+    ))
     return Hoods(
         vertex=vertex,
         hood_id=hood_id,
         valid=valid,
         sizes=sizes,
-        offsets=hood_offsets,
+        offsets=offsets,
         n_hoods=c,
         n_regions=n,
-        n_elements=int(np.asarray(jnp.sum(valid.astype(jnp.int32)))),
+        n_elements=n_elements,
         rep_old_index=rep[0],
         rep_test_label=rep[1],
         rep_hood_id=rep[2],
@@ -99,17 +153,29 @@ def build_hoods(graph: RegionGraph, cliques: CliqueSet) -> Hoods:
     )
 
 
-@functools.partial(jax.jit, static_argnames=("n_regions", "neighbor_capacity"))
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+@functools.partial(jax.jit, static_argnames=("n_regions", "n_lanes"))
 def _hood_arrays(
-    members, offsets, neighbors, *, n_regions: int, neighbor_capacity: int
+    members, offsets, neighbors, c, h_nat, *, n_regions: int, n_lanes: int
 ):
-    """Steps 1, 3 and 4 plus the label replication, for :func:`build_hoods`."""
+    """Steps 1, 3 and 4 plus the label replication, for :func:`build_hoods`.
+
+    Shapes are the class's: ``members`` is ``(C_pad, W)`` with rows of -1
+    past the ``c`` real cliques, ``neighbors`` is padded, and there are
+    ``n_lanes`` key lanes.  The natural counts ``c`` and ``h_nat`` are
+    traced, and every value that depended on them is computed from them,
+    so the first ``h_nat`` lanes (``2 * h_nat`` replication lanes, ``c``
+    sizes, ``c + 1`` offsets) equal a build at the natural shapes.
+    """
     n = n_regions
-    c, w = members.shape
-    members_flat = members.reshape(-1)                # (C*W,)
-    clique_of_slot = jnp.repeat(jnp.arange(c, dtype=jnp.int32), w)
+    c_pad, w = members.shape
+    members_flat = members.reshape(-1)                # (C_pad*W,)
+    clique_of_slot = jnp.repeat(jnp.arange(c_pad, dtype=jnp.int32), w)
     valid_slot = members_flat >= 0
-    n_slots = c * w
+    n_slots = c_pad * w
     deg = offsets[1:] - offsets[:-1]
 
     safe_member = jnp.where(valid_slot, members_flat, 0)
@@ -118,7 +184,8 @@ def _hood_arrays(
     slot_counts = jnp.where(valid_slot, deg[safe_member], 0).astype(jnp.int32)
 
     # -- Step 3: Get Neighbors (Map over expanded lanes). ------------------
-    src_slot, rank = dpp.expand_with_rank(slot_counts, neighbor_capacity)
+    # Lanes past the slots' total count fall outside every slot (invalid).
+    src_slot, rank = dpp.expand_with_rank(slot_counts, n_lanes - n_slots)
     lane_valid = src_slot < n_slots
     safe_slot = jnp.minimum(src_slot, n_slots - 1)
     v = safe_member[safe_slot]
@@ -134,20 +201,23 @@ def _hood_arrays(
     member_keys_v = safe_member
 
     span = n + 1
-    sentinel = c * span + n  # decodes to (hood_id=c, vertex=n)
+    # Decodes to (hood_id=c_pad, vertex=n): above every real key, as the
+    # natural build's (c, n) is, so real keys sort the same.
+    sentinel = c_pad * span + n
 
     # compound_key verifies the (cliqueId+1, vertexId+1) key space fits the
     # enabled integer width (int32 when jax_enable_x64 is off) instead of
-    # silently wrapping — the sentinel (c, n) is the largest key we pack.
+    # silently wrapping — the sentinel (c_pad, n) is the largest key we pack.
     key_nb = jnp.where(
-        cand_valid_nb, dpp.compound_key(cid, nb, span, major_span=c + 1), sentinel
+        cand_valid_nb, dpp.compound_key(cid, nb, span, major_span=c_pad + 1),
+        sentinel,
     )
     key_mem = jnp.where(
         valid_slot,
-        dpp.compound_key(member_keys_cid, member_keys_v, span, major_span=c + 1),
+        dpp.compound_key(member_keys_cid, member_keys_v, span, major_span=c_pad + 1),
         sentinel,
     )
-    keys = jnp.concatenate([key_mem, key_nb])  # (neighbor_capacity + n_slots,)
+    keys = jnp.concatenate([key_mem, key_nb])  # (n_lanes,)
 
     # -- Step 4: Remove Duplicate Neighbors (SortByKey + Unique). ----------
     (sorted_keys,) = dpp.sort_by_key(keys)
@@ -162,16 +232,15 @@ def _hood_arrays(
     valid = uniq != sentinel
 
     sizes = dpp.reduce_by_key(
-        jnp.where(valid, hood_id, c),
+        jnp.where(valid, hood_id, c_pad),
         valid.astype(jnp.int32),
-        c + 1,
+        c_pad + 1,
         op="add",
-    )[:c]
+    )[:c_pad]
     hood_offsets = dpp.counts_to_offsets(sizes)
 
     # -- Replication by label (paper: Map + Scan + Gather, memory-free). ---
-    h_pad = int(vertex.shape[0])
-    rep = _build_replication(hood_id, valid, sizes, hood_offsets, c, h_pad)
+    rep = _build_replication(valid, sizes, hood_offsets, c, h_nat)
     return vertex, jnp.where(valid, hood_id, c), valid, sizes, hood_offsets, rep
 
 
@@ -258,12 +327,11 @@ def _pad_hoods(h: Hoods, *, capacity: int, n_hoods: int, n_regions: int,
 
 
 def _build_replication(
-    hood_id: jnp.ndarray,
     valid: jnp.ndarray,
     sizes: jnp.ndarray,
     hood_offsets: jnp.ndarray,
-    n_hoods: int,
-    h_pad: int,
+    n_hoods: jnp.ndarray,
+    h_nat: jnp.ndarray,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Paper's testLabel / oldIndex / hoodId arrays of size 2*|hoods|.
 
@@ -275,29 +343,33 @@ def _build_replication(
     storage order, oldIndex points into the *packed* order; we therefore
     also need the packed->padded map, folded in here so rep_old_index
     indexes the padded arrays directly.
+
+    ``n_hoods`` and ``h_nat`` (the natural hood count and lane count) are
+    traced; lanes and hoods past them are padding of the class shape, and
+    fills and clamps use the natural counts.
     """
+    n_lanes = valid.shape[0]
     # Packed position of each padded lane (exclusive scan of valid flags).
     vi = valid.astype(jnp.int32)
     packed_pos = (jnp.cumsum(vi) - vi).astype(jnp.int32)
     # padded index of each packed element:
     pad_of_packed = dpp.scatter_(
-        jnp.arange(h_pad, dtype=jnp.int32), packed_pos, h_pad, mode="set",
-        fill=h_pad - 1, mask=valid,
+        jnp.arange(n_lanes, dtype=jnp.int32), packed_pos, n_lanes, mode="set",
+        fill=h_nat - 1, mask=valid,
     )
 
     rep_counts = (2 * sizes).astype(jnp.int32)
-    total = 2 * h_pad
-    rep_hood, rep_rank = dpp.expand_with_rank(rep_counts, total)
+    rep_hood, rep_rank = dpp.expand_with_rank(rep_counts, 2 * n_lanes)
     rep_lane_valid = rep_hood < n_hoods
     safe_hood = jnp.minimum(rep_hood, n_hoods - 1)
     s = sizes[safe_hood]
     o = hood_offsets[safe_hood]
     test_label = jnp.where(rep_rank >= s, 1, 0).astype(jnp.int32)
     packed_idx = o + jnp.where(rep_rank >= s, rep_rank - s, rep_rank)
-    packed_idx = jnp.minimum(packed_idx, h_pad - 1)
+    packed_idx = jnp.minimum(packed_idx, h_nat - 1)
     old_index = pad_of_packed[packed_idx]
     return (
-        jnp.where(rep_lane_valid, old_index, h_pad - 1).astype(jnp.int32),
+        jnp.where(rep_lane_valid, old_index, h_nat - 1).astype(jnp.int32),
         jnp.where(rep_lane_valid, test_label, 0),
         jnp.where(rep_lane_valid, rep_hood, n_hoods).astype(jnp.int32),
         rep_lane_valid,

@@ -25,7 +25,12 @@ from repro.core.pmrf import em as em_mod
 from repro.core.pmrf.cliques import CliqueSet, enumerate_maximal_cliques
 from repro.core.pmrf.energy import EnergyModel, make_energy_model
 from repro.core.pmrf.graph import RegionGraph, build_region_graph
-from repro.core.pmrf.hoods import Hoods, build_hoods
+from repro.core.pmrf.hoods import (
+    DEFAULT_CAPACITY_BUCKET,
+    DEFAULT_SEGMENT_BUCKET,
+    Hoods,
+    build_hoods,
+)
 
 
 @dataclass
@@ -69,13 +74,18 @@ def initialize(
     sigma_min: float = 2.0,
     n_labels: int = 2,
     oversegmentation=None,
+    capacity_bucket: int = DEFAULT_CAPACITY_BUCKET,
+    segment_bucket: int = DEFAULT_SEGMENT_BUCKET,
 ) -> Problem:
     """Initialization phase (paper Alg. 2 lines 1-5): graph + cliques +
     neighborhoods.  Untimed in the paper's methodology but fully built;
     each stage is a span (``plan.slic``, ``plan.graph``, ``plan.cliques``,
     ``plan.hoods``, ``plan.model``).
     ``n_labels`` sizes the model's label axis (K-ary segmentation,
-    DESIGN.md §13); the graph/clique/hood structure is label-free."""
+    DESIGN.md §13); the graph/clique/hood structure is label-free.
+    ``capacity_bucket`` / ``segment_bucket`` are the bucket grid on which
+    the hood program compiles (one program per shape class, DESIGN.md §2);
+    they do not change the result."""
     with obs.span("plan.slic"):
         img = jnp.asarray(image, jnp.float32)
         if oversegmentation is None:
@@ -92,7 +102,10 @@ def initialize(
     with obs.span("plan.cliques"):
         cliques = enumerate_maximal_cliques(graph)
     with obs.span("plan.hoods"):
-        hoods = build_hoods(graph, cliques)
+        hoods = build_hoods(
+            graph, cliques, capacity_bucket=capacity_bucket,
+            segment_bucket=segment_bucket,
+        )
     with obs.span("plan.model"):
         model = make_energy_model(
             graph.region_mean, graph.region_size, beta=beta, sigma_min=sigma_min,

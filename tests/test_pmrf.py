@@ -22,9 +22,14 @@ from repro.core.pmrf import (
     run_em,
     segment_image,
 )
-from repro.core.pmrf.cliques import verify_maximal_cliques
+from repro.analysis import budget
+from repro.core.pmrf.cliques import CliqueSet, verify_maximal_cliques
 from repro.core.pmrf import em as em_mod
 from repro.core.pmrf import energy as energy_mod
+from repro.core.pmrf import hoods as hoods_mod
+from repro import obs
+
+from _natural_hoods import natural_hoods
 
 
 def _tiny_problem(seed=0, shape=(40, 40), grid=(6, 6)):
@@ -129,6 +134,109 @@ def test_hoods_structure():
     # ... once per test label
     rep_lab = np.asarray(hoods.rep_test_label)[np.asarray(hoods.rep_valid)]
     assert rep_lab.sum() == valid.sum()
+
+
+def _voronoi_graph(seed, n_regions=60, shape=(40, 40)):
+    """Region graph of a random Voronoi partition: a random planar graph."""
+    rng = np.random.default_rng(seed)
+    cells = rng.choice(shape[0] * shape[1], n_regions, replace=False)
+    cy, cx = np.divmod(cells, shape[1])
+    yy, xx = np.mgrid[: shape[0], : shape[1]]
+    d = (yy[..., None] - cy) ** 2 + (xx[..., None] - cx) ** 2
+    lab = np.argmin(d, axis=-1).astype(np.int32)
+    img = rng.uniform(0, 255, shape).astype(np.float32)
+    return build_region_graph(img, lab, n_regions)
+
+
+def _widened(cs, extra):
+    """The same cliques with ``extra`` more -1 columns (a wider W)."""
+    return CliqueSet(np.pad(cs.members, ((0, 0), (0, extra)), constant_values=-1),
+                     cs.sizes)
+
+
+@pytest.fixture(scope="module")
+def slic512_graph():
+    vol = synthetic.make_synthetic_volume(seed=2, n_slices=1, shape=(512, 512))
+    img = np.asarray(vol.images[0])
+    lab = oversegment.slic(jnp.asarray(img), grid=(8, 8), iters=3)
+    return build_region_graph(img, lab, 64)
+
+
+def _natural_lanes(g, cs):
+    deg = np.diff(g.csr_offsets)
+    return int(deg[cs.members[cs.members >= 0]].sum()) + cs.members.size
+
+
+# (graph, capacity_bucket, segment_bucket); a bucket given as a string is
+# derived from the graph: "lanes" puts the natural lane count exactly on a
+# capacity boundary, "cliques" makes C a multiple of segment_bucket.
+_HOOD_CASES = {
+    "voronoi-0": ("voronoi-0", 256, 64),
+    "voronoi-1": ("voronoi-1", 1000, 7),
+    "voronoi-2": ("voronoi-2", 1, 1),
+    "voronoi-3-wide": ("voronoi-3-wide", 4096, 1024),
+    "capacity-on-boundary": ("voronoi-4", "lanes", 1),
+    "cliques-multiple-of-bucket": ("voronoi-5", 256, "cliques"),
+    "slic512": ("slic512", 4096, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_HOOD_CASES))
+def test_build_hoods_matches_natural_oracle(case, request):
+    graph_name, cap_b, seg_b = _HOOD_CASES[case]
+    if graph_name == "slic512":
+        g = request.getfixturevalue("slic512_graph")
+    else:
+        g = _voronoi_graph(int(graph_name.split("-")[1]))
+    cs = enumerate_maximal_cliques(g)
+    if graph_name.endswith("-wide"):
+        cs = _widened(cs, 2)
+    if cap_b == "lanes":
+        cap_b = _natural_lanes(g, cs)
+    if seg_b == "cliques":
+        seg_b = cs.n_cliques // 2 if cs.n_cliques % 2 == 0 else cs.n_cliques
+        assert cs.n_cliques % seg_b == 0
+    got = build_hoods(g, cs, capacity_bucket=cap_b, segment_bucket=seg_b)
+    want = natural_hoods(g, cs)
+
+    got_leaves, got_def = jax.tree_util.tree_flatten(got)
+    want_leaves, want_def = jax.tree_util.tree_flatten(want)
+    assert got_def == want_def  # n_hoods, n_regions, n_elements included
+    assert (got.n_hoods, got.n_regions, got.n_elements) == (
+        want.n_hoods, want.n_regions, want.n_elements)
+    for field in ("vertex", "hood_id", "valid", "sizes", "offsets", "rep_old_index",
+                  "rep_test_label", "rep_hood_id", "rep_valid"):
+        a, b = getattr(got, field), getattr(want, field)
+        assert a.dtype == b.dtype and a.shape == b.shape, field
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=field)
+    assert len(got_leaves) == len(want_leaves)
+
+
+def test_hood_program_compiles_once_per_class():
+    # Buckets no other test uses, so the class is new to this process.
+    grid = dict(capacity_bucket=8191, segment_bucket=509)
+    g1, g2 = _voronoi_graph(11), _voronoi_graph(12)
+    cs1, cs2 = enumerate_maximal_cliques(g1), enumerate_maximal_cliques(g2)
+    w = max(cs1.width, cs2.width)
+    cs1, cs2 = _widened(cs1, w - cs1.width), _widened(cs2, w - cs2.width)
+    assert cs1.n_cliques != cs2.n_cliques
+    assert g1.csr_neighbors.shape != g2.csr_neighbors.shape
+    assert _natural_lanes(g1, cs1) != _natural_lanes(g2, cs2)
+
+    plan = budget.LEDGER.section("plan")
+    with obs.recording() as rec:
+        with obs.span("plan.hoods"):
+            h1 = build_hoods(g1, cs1, **grid)
+        assert (plan.get("hood_class_miss", 0), plan.get("hood_class_hit", 0)) == (1, 0)
+        with obs.span("plan.hoods"):
+            h2 = build_hoods(g2, cs2, **grid)
+        assert (plan.get("hood_class_miss", 0), plan.get("hood_class_hit", 0)) == (1, 1)
+        with obs.span("plan.hoods"):
+            build_hoods(g1, _widened(cs1, 1), **grid)  # a wider W: a new class
+    first, second, wider = rec.records
+    assert (first.compiles, second.compiles, wider.compiles) == (1, 0, 1)
+    assert (plan["hood_class_miss"], plan["hood_class_hit"]) == (2, 1)
+    assert h1.capacity != h2.capacity  # natural shapes out, one program in
 
 
 # ---------------------------------------------------------------------------
