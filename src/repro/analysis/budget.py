@@ -15,7 +15,9 @@ section    who writes it
            (``lower_compile``) and every warm LRU hit (``warm_hit``)
 "serve"    the serving engine records ``ticks`` and ``lane_steps``
 "plan"     ``hoods.build_hoods`` records ``hood_class_miss`` (the first
-           build of a shape class in the process) and ``hood_class_hit``
+           build of a shape class in the process) and ``hood_class_hit``;
+           ``cliques.enumerate_maximal_cliques`` ``clique_candidates`` (the
+           vertices it tested for adjacency to a clique's members)
 =========  ==========================================================
 
 On top of the ledger sit *declared phase budgets*: the zero-retrace /

@@ -11,12 +11,15 @@ each clique is grown only by vertices larger than its current maximum, so
 every k-clique is generated exactly once through its sorted prefix chain —
 and emit a clique when its common-neighbor set is empty (the maximality
 test).  The iteration depth equals the largest clique size (3-5 here), and
-every level is a dense, vectorized membership computation over the
-adjacency matrix: this is the Map/Scan/Scatter formulation of MCE
-specialized to bounded clique number.
+every level is one vectorized pass over the graph's CSR: each clique's
+candidates are the neighbors of its last member (Map + Expand), each
+candidate is tested for adjacency to the other members by binary search
+of the sorted edge keys, and a clique with no candidate adjacent to every
+member is maximal.  Work and memory are linear in the candidates, never
+(cliques x regions).
 
 Runs in the initialization phase (untimed in the paper's methodology);
-implemented in numpy for clarity, dense-vectorized per level.
+implemented in numpy on the host.
 """
 
 from __future__ import annotations
@@ -26,6 +29,7 @@ from typing import List
 
 import numpy as np
 
+from repro.analysis import budget as budget_mod
 from repro.core.pmrf.graph import RegionGraph
 
 
@@ -45,10 +49,23 @@ class CliqueSet:
         return int(self.members.shape[1])
 
 
+def _row_of(graph: RegionGraph, vertices: np.ndarray):
+    """The CSR rows of ``vertices``, concatenated: (owner index, neighbor)
+    per entry, in row order and ascending within each row."""
+    start = graph.csr_offsets[vertices].astype(np.int64)
+    counts = graph.csr_offsets[vertices + 1] - start
+    owner = np.repeat(np.arange(vertices.shape[0]), counts)
+    first = np.cumsum(counts) - counts
+    idx = np.arange(owner.shape[0]) + np.repeat(start - first, counts)
+    return owner, graph.csr_neighbors[idx]
+
+
 def enumerate_maximal_cliques(
     graph: RegionGraph, max_size: int | None = None, max_frontier: int = 2_000_000
 ) -> CliqueSet:
-    adj = graph.adj
+    """Every maximal clique: isolated vertices first, then by size, then
+    lexicographically.  Counts the candidates tested in ``LEDGER``
+    ``plan.clique_candidates``."""
     n = graph.n_regions
     if max_size is None:
         max_size = n  # loop to exhaustion; RAG clique number is small
@@ -56,29 +73,31 @@ def enumerate_maximal_cliques(
     maximal: List[np.ndarray] = []  # list of (m_k, k) arrays
 
     # Level 1: isolated vertices are maximal 1-cliques.
-    deg = adj.sum(axis=1)
-    isolated = np.nonzero(deg == 0)[0].astype(np.int32)
+    isolated = np.nonzero(graph.degree() == 0)[0].astype(np.int32)
     if isolated.size:
         maximal.append(isolated[:, None])
 
-    # Level 2 seeds: all edges (u < v).
+    # Level 2 seeds: all edges (u < v), in key order.
     cliques = graph.edges.astype(np.int32)  # (m, 2)
 
     k = 2
     while cliques.size and k <= max_size:
-        # Common neighbors of all members: AND of adjacency rows.
-        common = np.ones((cliques.shape[0], n), dtype=bool)
-        for col in range(k):
-            common &= adj[cliques[:, col]]
-        is_max = ~common.any(axis=1)
-        if is_max.any():
-            maximal.append(cliques[is_max])
+        # A common neighbor of every member is a neighbor of the last one:
+        # test each of its neighbors against the other members (a member
+        # is never adjacent to itself, so members drop out).
+        owner, cand = _row_of(graph, cliques[:, -1])
+        budget_mod.LEDGER.bump("plan", "clique_candidates", int(cand.shape[0]))
+        common = np.ones(cand.shape, bool)
+        for col in range(k - 1):
+            common &= graph.adjacent(cliques[owner, col], cand)
+        has_common = np.zeros(cliques.shape[0], bool)
+        has_common[owner[common]] = True
+        if not has_common.all():
+            maximal.append(cliques[~has_common])
 
         # Canonical extension: only w > max(member ids) = last column.
-        ext = common.copy()
-        col_idx = np.arange(n)[None, :]
-        ext &= col_idx > cliques[:, -1:]
-        rows, cols = np.nonzero(ext)
+        ext = common & (cand > cliques[owner, -1])
+        rows, cols = owner[ext], cand[ext]
         if rows.size == 0:
             break
         if rows.size > max_frontier:
@@ -111,16 +130,15 @@ def enumerate_maximal_cliques(
 def verify_maximal_cliques(graph: RegionGraph, cliques: CliqueSet) -> bool:
     """Oracle check used by tests: every row is a clique, and no row can be
     extended by any vertex (maximality)."""
-    adj = graph.adj
     for row, size in zip(cliques.members, cliques.sizes):
         mem = row[:size]
-        for i in range(size):
-            for j in range(i + 1, size):
-                if not adj[mem[i], mem[j]]:
-                    return False
-        common = np.ones(graph.n_regions, dtype=bool)
+        i, j = np.triu_indices(size, 1)
+        if not graph.adjacent(mem[i], mem[j]).all():
+            return False
+        _, cand = _row_of(graph, mem[:1])
+        common = np.ones(cand.shape, bool)
         for v in mem:
-            common &= adj[v]
+            common &= graph.adjacent(np.full(cand.shape, v), cand)
         if common.any():
             return False
     return True

@@ -4,10 +4,10 @@ Each vertex is an oversegmented region; an edge connects regions whose
 pixels share a boundary.  Region statistics (mean intensity = the MRF data
 term source, pixel counts = M-step weights) are computed with ReduceByKey
 over the pixel label map.  The graph is stored in CSR form (the paper's
-compressed sparse row representation, following [23]) plus a dense
-adjacency matrix used by the clique enumerator — region counts are small
-(hundreds to a few thousand), so the dense form is cheap and maps onto
-TPU-friendly regular compute.
+compressed sparse row representation, following [23]) next to its sorted
+edge keys, which answer "are u and v adjacent" by binary search: nothing
+is (regions x regions), so a full 1813x1830 slice at ~52k regions costs
+memory in its edges alone.
 
 Construction runs in the *initialization* phase (the paper times only the
 optimization loop), so host-side numpy is used where it is clearer;
@@ -27,16 +27,15 @@ from repro.core import dpp
 
 @dataclass
 class RegionGraph:
-    """CSR + dense adjacency + per-region statistics."""
+    """CSR + sorted edge keys + per-region statistics."""
 
     n_regions: int
-    edges: np.ndarray          # (E, 2) int32, u < v, deduped
+    edges: np.ndarray          # (E, 2) int32, u < v, deduped, sorted
+    edge_keys: np.ndarray      # (E,) int64 u * n_regions + v, ascending
     csr_offsets: np.ndarray    # (n_regions + 1,) int32
-    csr_neighbors: np.ndarray  # (2E,) int32
-    adj: np.ndarray            # (n_regions, n_regions) bool, zero diagonal
+    csr_neighbors: np.ndarray  # (2E,) int32, ascending within each row
     region_mean: np.ndarray    # (n_regions,) float32 — MRF data term
     region_size: np.ndarray    # (n_regions,) float32 — pixel counts
-    max_degree: int
 
     @property
     def n_edges(self) -> int:
@@ -44,6 +43,39 @@ class RegionGraph:
 
     def degree(self) -> np.ndarray:
         return np.diff(self.csr_offsets)
+
+    @classmethod
+    def from_edge_keys(cls, key: np.ndarray, n_regions: int, region_mean,
+                       region_size) -> "RegionGraph":
+        """The graph of the unique, ascending edge keys ``u * n_regions + v``
+        (``u < v``): the CSR holds both directions of every edge, sorted by
+        (row, column)."""
+        key = np.asarray(key, np.int64)
+        eu, ev = key // n_regions, key % n_regions
+        arcs = np.sort(np.concatenate([key, ev * n_regions + eu]))
+        deg = np.bincount(arcs // n_regions, minlength=n_regions).astype(np.int32)
+        offsets = np.zeros(n_regions + 1, dtype=np.int32)
+        np.cumsum(deg, out=offsets[1:])
+        return cls(
+            n_regions=n_regions,
+            edges=np.stack([eu, ev], axis=1).astype(np.int32),
+            edge_keys=key,
+            csr_offsets=offsets,
+            csr_neighbors=(arcs % n_regions).astype(np.int32),
+            region_mean=region_mean,
+            region_size=region_size,
+        )
+
+    def adjacent(self, u: np.ndarray, v: np.ndarray) -> np.ndarray:
+        """Elementwise: is there an edge between ``u`` and ``v``?  A binary
+        search of the sorted edge keys; ``u == v`` is never an edge."""
+        u, v = np.asarray(u, np.int64), np.asarray(v, np.int64)
+        key = np.minimum(u, v) * self.n_regions + np.maximum(u, v)
+        pos = np.searchsorted(self.edge_keys, key)
+        inside = pos < self.n_edges
+        found = np.zeros(key.shape, bool)
+        found[inside] = self.edge_keys[pos[inside]] == key[inside]
+        return found
 
 
 def region_stats(image, labels, n_regions: int) -> Tuple[np.ndarray, np.ndarray]:
@@ -76,28 +108,5 @@ def build_region_graph(image, labels, n_regions: int) -> RegionGraph:
     v = np.maximum(pairs[:, 0], pairs[:, 1])
     key = u * n_regions + v
     key = np.unique(key)  # SortByKey + Unique
-    eu = (key // n_regions).astype(np.int32)
-    ev = (key % n_regions).astype(np.int32)
-    edges = np.stack([eu, ev], axis=1)
-
-    adj = np.zeros((n_regions, n_regions), dtype=bool)
-    adj[eu, ev] = True
-    adj[ev, eu] = True
-
-    deg = adj.sum(axis=1).astype(np.int32)
-    offsets = np.zeros(n_regions + 1, dtype=np.int32)
-    np.cumsum(deg, out=offsets[1:])
-    neighbors = np.nonzero(adj)[1].astype(np.int32)  # row-major = CSR order
-
     mean, size = region_stats(image, labels, n_regions)
-
-    return RegionGraph(
-        n_regions=n_regions,
-        edges=edges,
-        csr_offsets=offsets,
-        csr_neighbors=neighbors,
-        adj=adj,
-        region_mean=mean,
-        region_size=size,
-        max_degree=int(deg.max(initial=0)),
-    )
+    return RegionGraph.from_edge_keys(key, n_regions, mean, size)

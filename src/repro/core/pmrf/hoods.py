@@ -13,7 +13,8 @@ layer:
   3. **Get Neighbors** (Map): populate candidate (cliqueId, vertexId)
      elements via the expand idiom (Scatter + max-Scan + Gather).
   4. **Remove Duplicate Neighbors** (SortByKey + Unique): sort candidates
-     by (cliqueId, vertexId) compound key, drop adjacent duplicates.
+     by the (cliqueId, vertexId) pair, two keys, and drop adjacent
+     duplicates.
 
 It also builds the paper's label-replication index arrays (testLabel,
 oldIndex, hoodId — the "repHoods" simulated, memory-free Gather).
@@ -97,35 +98,39 @@ def build_hoods(
         raise ValueError("no cliques — empty graph?")
 
     # Step 2's capacity, counted on the host from the graph: one candidate
-    # per (clique member, 1-hop neighbor) pair; with the member lanes it
-    # gives the natural lane count.
+    # per (clique member, 1-hop neighbor) pair; with the natural build's
+    # C·W member lanes it gives the natural lane count, the capacity the
+    # Hoods keep (every slice's own members fit in C·W lanes).
     members = cliques.members
-    w = cliques.width
     deg = np.diff(graph.csr_offsets)
-    neighbor_capacity = int(deg[members[members >= 0]].sum())
-    h_nat = neighbor_capacity + c * w
+    slot_clique, slot_col = np.nonzero(members >= 0)  # row-major member slots
+    slot_member = members[slot_clique, slot_col]
+    neighbor_capacity = int(deg[slot_member].sum())
+    h_nat = neighbor_capacity + c * cliques.width
 
-    # Class shapes: clique rows padded with -1 (no member, so no key), the
-    # CSR neighbour array and the key lanes rounded up on the bucket grid.
+    # Class shapes: the clique count, the CSR neighbour array and the key
+    # lanes rounded up on the bucket grid; the key lanes are the session's
+    # bucket capacity.  The member slots (one per clique member, padded
+    # with -1: no member, so no key) take the lane count too, so neither
+    # the clique width nor the member count, which vary from slice to
+    # slice, is a dimension of the class.
     c_pad = _round_up(c, segment_bucket)
-    nnz = graph.csr_neighbors.shape[0]
-    members_pad = np.full((c_pad, w), -1, members.dtype)
-    members_pad[:c] = members
-    neighbors_pad = np.zeros(_round_up(max(nnz, 1), capacity_bucket),
-                             graph.csr_neighbors.dtype)
-    neighbors_pad[:nnz] = graph.csr_neighbors
-    n_lanes = _round_up(neighbor_capacity + c_pad * w, capacity_bucket)
-    shape_class = (c_pad, w, neighbors_pad.shape[0], n, n_lanes)
+    neighbors_pad = _padded(graph.csr_neighbors, _round_up(
+        max(graph.csr_neighbors.shape[0], 1), capacity_bucket), 0)
+    n_lanes = _round_up(h_nat, capacity_bucket)
+    shape_class = (c_pad, neighbors_pad.shape[0], n, n_lanes)
     hit = shape_class in _SEEN_CLASSES
     _SEEN_CLASSES.add(shape_class)
     budget_mod.LEDGER.bump("plan", "hood_class_hit" if hit else "hood_class_miss")
 
     out = _hood_arrays(
-        jnp.asarray(members_pad),
+        jnp.asarray(_padded(slot_member, n_lanes, -1)),
+        jnp.asarray(_padded(slot_clique.astype(np.int32), n_lanes, c_pad)),
         jnp.asarray(graph.csr_offsets),
         jnp.asarray(neighbors_pad),
         np.int32(c),
         np.int32(h_nat),
+        n_cliques=c_pad,
         n_regions=n,
         n_lanes=n_lanes,
     )
@@ -157,82 +162,68 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-@functools.partial(jax.jit, static_argnames=("n_regions", "n_lanes"))
+def _padded(x: np.ndarray, total: int, fill) -> np.ndarray:
+    out = np.full((total,), fill, x.dtype)
+    out[: x.shape[0]] = x
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("n_cliques", "n_regions", "n_lanes"))
 def _hood_arrays(
-    members, offsets, neighbors, c, h_nat, *, n_regions: int, n_lanes: int
+    slot_member, slot_clique, offsets, neighbors, c, h_nat, *,
+    n_cliques: int, n_regions: int, n_lanes: int,
 ):
     """Steps 1, 3 and 4 plus the label replication, for :func:`build_hoods`.
 
-    Shapes are the class's: ``members`` is ``(C_pad, W)`` with rows of -1
-    past the ``c`` real cliques, ``neighbors`` is padded, and there are
-    ``n_lanes`` key lanes.  The natural counts ``c`` and ``h_nat`` are
-    traced, and every value that depended on them is computed from them,
-    so the first ``h_nat`` lanes (``2 * h_nat`` replication lanes, ``c``
-    sizes, ``c + 1`` offsets) equal a build at the natural shapes.
+    Shapes are the class's: ``slot_member``/``slot_clique`` hold each
+    clique member and its clique in their first lanes (member -1 in the
+    rest), there are ``n_cliques`` clique ids (the real ``c`` and padding),
+    ``neighbors`` is padded, and there are ``n_lanes`` key lanes.  The
+    natural counts ``c`` and ``h_nat`` are traced, and every value that
+    depended on them is computed from them, so the first ``h_nat`` lanes
+    (``2 * h_nat`` replication lanes, ``c`` sizes, ``c + 1`` offsets) equal
+    a build at the natural shapes.
     """
     n = n_regions
-    c_pad, w = members.shape
-    members_flat = members.reshape(-1)                # (C_pad*W,)
-    clique_of_slot = jnp.repeat(jnp.arange(c_pad, dtype=jnp.int32), w)
-    valid_slot = members_flat >= 0
-    n_slots = c_pad * w
+    c_pad = n_cliques
+    valid_slot = slot_member >= 0
     deg = offsets[1:] - offsets[:-1]
+    safe_member = jnp.where(valid_slot, slot_member, 0)
 
-    safe_member = jnp.where(valid_slot, members_flat, 0)
-
-    # -- Step 1: Find Neighbors (Map) — per-slot neighbor counts. ----------
-    slot_counts = jnp.where(valid_slot, deg[safe_member], 0).astype(jnp.int32)
+    # -- Step 1: Find Neighbors (Map) — per-slot element counts: the member
+    # itself and its 1-hop neighbors. --------------------------------------
+    slot_counts = jnp.where(valid_slot, deg[safe_member] + 1, 0).astype(jnp.int32)
 
     # -- Step 3: Get Neighbors (Map over expanded lanes). ------------------
-    # Lanes past the slots' total count fall outside every slot (invalid).
-    src_slot, rank = dpp.expand_with_rank(slot_counts, n_lanes - n_slots)
-    lane_valid = src_slot < n_slots
-    safe_slot = jnp.minimum(src_slot, n_slots - 1)
+    # Each slot's lanes hold its member (rank 0), then the member's CSR row;
+    # lanes past the slots' total count fall outside every slot (invalid).
+    src_slot, rank = dpp.expand_with_rank(slot_counts, n_lanes)
+    lane_valid = src_slot < n_lanes
+    safe_slot = jnp.minimum(src_slot, n_lanes - 1)
     v = safe_member[safe_slot]
-    nb = neighbors[jnp.minimum(offsets[v] + rank, neighbors.shape[0] - 1)]
-    cid = clique_of_slot[safe_slot]
-    # Exclude neighbors that are members of the same clique (paper step 1's
-    # "not a member of the vertex's maximal clique" filter).
-    nb_in_clique = jnp.any(members[cid] == nb[:, None], axis=1)
-    cand_valid_nb = lane_valid & ~nb_in_clique
+    nb = neighbors[jnp.clip(offsets[v] + rank - 1, 0, neighbors.shape[0] - 1)]
+    element = jnp.where(rank == 0, v, nb)
 
-    # Clique members are hood elements too (hood = clique U 1-hop neighbors).
-    member_keys_cid = clique_of_slot
-    member_keys_v = safe_member
-
-    span = n + 1
-    # Decodes to (hood_id=c_pad, vertex=n): above every real key, as the
-    # natural build's (c, n) is, so real keys sort the same.
-    sentinel = c_pad * span + n
-
-    # compound_key verifies the (cliqueId+1, vertexId+1) key space fits the
-    # enabled integer width (int32 when jax_enable_x64 is off) instead of
-    # silently wrapping — the sentinel (c_pad, n) is the largest key we pack.
-    key_nb = jnp.where(
-        cand_valid_nb, dpp.compound_key(cid, nb, span, major_span=c_pad + 1),
-        sentinel,
-    )
-    key_mem = jnp.where(
-        valid_slot,
-        dpp.compound_key(member_keys_cid, member_keys_v, span, major_span=c_pad + 1),
-        sentinel,
-    )
-    keys = jnp.concatenate([key_mem, key_nb])  # (n_lanes,)
+    # Hood = clique U 1-hop neighbors.  Every lane is a (cliqueId, vertexId)
+    # pair; lanes that hold no element carry the sentinel (c_pad, n), above
+    # every real pair as the natural build's (c, n) is, so real pairs sort
+    # the same.  A neighbor that is a member of the same clique repeats that
+    # member's pair, and Unique drops it (the paper filters it out in step
+    # 1).  The pair is sorted as two keys, never packed into one integer:
+    # (C_pad + 1)(n + 1) passes int32 on a full 1813x1830 slice.
+    key_cid = jnp.where(lane_valid, slot_clique[safe_slot], c_pad)
+    key_v = jnp.where(lane_valid, element, n)
 
     # -- Step 4: Remove Duplicate Neighbors (SortByKey + Unique). ----------
-    (sorted_keys,) = dpp.sort_by_key(keys)
-    uniq, count = dpp.unique_(sorted_keys, fill=sentinel)
-    # Padding lanes of unique_ carry ``fill``; also drop the sentinel itself
-    # if it survived as a "unique" value.
-    lane = jnp.arange(uniq.shape[0])
-    uniq = jnp.where((lane < count) & (uniq != sentinel), uniq, sentinel)
-
-    hood_id = (uniq // span).astype(jnp.int32)
-    vertex = (uniq % span).astype(jnp.int32)
-    valid = uniq != sentinel
+    # Only the keys are sorted, so equal lanes are interchangeable.
+    sorted_keys = dpp.sort_by_key((key_cid, key_v), num_keys=2, stable=False)
+    # Padding lanes of unique_ carry the sentinel, which a surviving
+    # sentinel pair also is: a lane is an element iff its clique is real.
+    (hood_id, vertex), _ = dpp.unique_(sorted_keys, fill=(c_pad, n))
+    valid = hood_id != c_pad
 
     sizes = dpp.reduce_by_key(
-        jnp.where(valid, hood_id, c_pad),
+        hood_id,
         valid.astype(jnp.int32),
         c_pad + 1,
         op="add",
@@ -351,7 +342,7 @@ def _build_replication(
     n_lanes = valid.shape[0]
     # Packed position of each padded lane (exclusive scan of valid flags).
     vi = valid.astype(jnp.int32)
-    packed_pos = (jnp.cumsum(vi) - vi).astype(jnp.int32)
+    packed_pos = dpp.scan_(vi, exclusive=True)
     # padded index of each packed element:
     pad_of_packed = dpp.scatter_(
         jnp.arange(n_lanes, dtype=jnp.int32), packed_pos, n_lanes, mode="set",

@@ -6,6 +6,9 @@ energy monotonicity, and the paper's verification claim (high accuracy vs.
 ground truth on the synthetic porous-media benchmark, §4.2.2).
 """
 
+import pathlib
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,12 +27,18 @@ from repro.core.pmrf import (
 )
 from repro.analysis import budget
 from repro.core.pmrf.cliques import CliqueSet, verify_maximal_cliques
+from repro.core.pmrf.graph import RegionGraph
 from repro.core.pmrf import em as em_mod
 from repro.core.pmrf import energy as energy_mod
 from repro.core.pmrf import hoods as hoods_mod
 from repro import obs
 
+from _dense_cliques import dense_adjacency, dense_maximal_cliques
+from _hyp import given, settings, st
 from _natural_hoods import natural_hoods
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+from bench import reference as bench_reference  # noqa: E402
 
 
 def _tiny_problem(seed=0, shape=(40, 40), grid=(6, 6)):
@@ -72,10 +81,11 @@ def test_region_graph_matches_bruteforce():
         np.testing.assert_allclose(g.region_mean[r], img[mask].mean(), rtol=1e-5)
         assert g.region_size[r] == mask.sum()
 
-    # CSR is consistent with the dense adjacency
+    # CSR rows are the brute-force neighbor sets, ascending within each row
     for v in range(5):
-        nbrs = set(g.csr_neighbors[g.csr_offsets[v] : g.csr_offsets[v + 1]].tolist())
-        assert nbrs == set(np.nonzero(g.adj[v])[0].tolist())
+        row = g.csr_neighbors[g.csr_offsets[v] : g.csr_offsets[v + 1]].tolist()
+        assert row == sorted({b if a == v else a for a, b in want_edges if v in (a, b)})
+    assert g.edge_keys.tolist() == sorted(a * 5 + b for a, b in want_edges)
 
 
 def test_cliques_are_maximal_on_random_planarish_graph():
@@ -95,12 +105,74 @@ def test_cliques_are_maximal_on_random_planarish_graph():
     assert {tuple(e) for e in g.edges.tolist()} <= covered
 
 
+def _edge_graph(n, pairs):
+    """A RegionGraph of ``n`` vertices and the undirected edges ``pairs``."""
+    keys = np.unique(np.array(
+        [min(a, b) * n + max(a, b) for a, b in pairs if a != b], np.int64))
+    return RegionGraph.from_edge_keys(
+        keys, n, np.zeros(n, np.float32), np.ones(n, np.float32))
+
+
+def _neighbour_sets(g):
+    return [set(g.csr_neighbors[g.csr_offsets[v]:g.csr_offsets[v + 1]].tolist())
+            for v in range(g.n_regions)]
+
+
+def _assert_cliques_match_oracles(g):
+    """The CSR enumerator equals the dense one (members, order, width) and
+    the benchmark's Bron-Kerbosch reference."""
+    got, want = enumerate_maximal_cliques(g), dense_maximal_cliques(g)
+    assert got.members.dtype == want.members.dtype
+    np.testing.assert_array_equal(got.members, want.members)
+    np.testing.assert_array_equal(got.sizes, want.sizes)
+    rows = [tuple(r[:k].tolist()) for r, k in zip(got.members, got.sizes)]
+    assert rows == bench_reference.maximal_cliques(_neighbour_sets(g))
+    assert verify_maximal_cliques(g, got)
+    return got
+
+
+@settings(max_examples=80, deadline=None)
+@given(st.integers(1, 14),
+       st.lists(st.tuples(st.integers(0, 13), st.integers(0, 13)), max_size=70))
+def test_csr_cliques_match_dense_on_random_graphs(n, pairs):
+    _assert_cliques_match_oracles(_edge_graph(n, [(a % n, b % n) for a, b in pairs]))
+
+
+@pytest.mark.parametrize("shape,grid,seed", [
+    ((40, 40), (6, 6), 0), ((96, 80), (12, 10), 1), ((128, 128), (16, 16), 2),
+    ((181, 183), (23, 23), 3),
+])
+def test_csr_cliques_match_dense_on_slic_graphs(shape, grid, seed):
+    vol = synthetic.make_synthetic_volume(seed=seed, n_slices=1, shape=shape)
+    img = np.asarray(vol.images[0])
+    lab = oversegment.slic(jnp.asarray(img), grid=grid, iters=3)
+    g = build_region_graph(img, lab, grid[0] * grid[1])
+    before = budget.LEDGER.section("plan").get("clique_candidates", 0)
+    cs = _assert_cliques_match_oracles(g)
+    assert cs.width >= 3
+    # Level 2 alone tests every neighbor of every edge's larger end.
+    grown = budget.LEDGER.section("plan")["clique_candidates"] - before
+    assert grown >= int(g.degree()[g.edges[:, 1]].sum())
+
+
+def test_verify_maximal_cliques_refuses_non_maximal_and_non_cliques():
+    g = _edge_graph(4, [(0, 1), (1, 2), (0, 2), (2, 3)])
+    good = enumerate_maximal_cliques(g)
+    assert good.members.tolist() == [[2, 3, -1], [0, 1, 2]]
+    assert verify_maximal_cliques(g, good)
+    extendable = CliqueSet(np.array([[0, 1]], np.int32), np.array([2], np.int32))
+    assert not verify_maximal_cliques(g, extendable)
+    not_a_clique = CliqueSet(np.array([[0, 3]], np.int32), np.array([2], np.int32))
+    assert not verify_maximal_cliques(g, not_a_clique)
+
+
 def test_hoods_structure():
     img, _ = _tiny_problem()
     lab = oversegment.slic(jnp.asarray(img), grid=(6, 6), iters=3)
     g = build_region_graph(img, lab, 36)
     cs = enumerate_maximal_cliques(g)
     hoods = build_hoods(g, cs)
+    adj = dense_adjacency(g)
 
     vertex = np.asarray(hoods.vertex)
     hood_id = np.asarray(hoods.hood_id)
@@ -118,7 +190,7 @@ def test_hoods_structure():
         mem = cs.members[h][: cs.sizes[h]].tolist()
         want = set(mem)
         for m in mem:
-            want |= set(np.nonzero(g.adj[m])[0].tolist())
+            want |= set(np.nonzero(adj[m])[0].tolist())
         assert got.get(h, set()) == want, f"hood {h} mismatch"
         assert sizes[h] == len(want)
 
@@ -217,8 +289,6 @@ def test_hood_program_compiles_once_per_class():
     grid = dict(capacity_bucket=8191, segment_bucket=509)
     g1, g2 = _voronoi_graph(11), _voronoi_graph(12)
     cs1, cs2 = enumerate_maximal_cliques(g1), enumerate_maximal_cliques(g2)
-    w = max(cs1.width, cs2.width)
-    cs1, cs2 = _widened(cs1, w - cs1.width), _widened(cs2, w - cs2.width)
     assert cs1.n_cliques != cs2.n_cliques
     assert g1.csr_neighbors.shape != g2.csr_neighbors.shape
     assert _natural_lanes(g1, cs1) != _natural_lanes(g2, cs2)
@@ -232,11 +302,13 @@ def test_hood_program_compiles_once_per_class():
             h2 = build_hoods(g2, cs2, **grid)
         assert (plan.get("hood_class_miss", 0), plan.get("hood_class_hit", 0)) == (1, 1)
         with obs.span("plan.hoods"):
-            build_hoods(g1, _widened(cs1, 1), **grid)  # a wider W: a new class
+            # The clique width is no class dimension: it varies per slice.
+            h3 = build_hoods(g1, _widened(cs1, 2), **grid)
     first, second, wider = rec.records
-    assert (first.compiles, second.compiles, wider.compiles) == (1, 0, 1)
-    assert (plan["hood_class_miss"], plan["hood_class_hit"]) == (2, 1)
+    assert (first.compiles, second.compiles, wider.compiles) == (1, 0, 0)
+    assert (plan["hood_class_miss"], plan["hood_class_hit"]) == (1, 2)
     assert h1.capacity != h2.capacity  # natural shapes out, one program in
+    assert h3.capacity == h1.capacity + 2 * cs1.n_cliques
 
 
 # ---------------------------------------------------------------------------

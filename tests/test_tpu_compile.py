@@ -10,7 +10,9 @@ about results or time.
 
 Shapes are those of the fused path at the paper's 512x512 slice with a
 16x16 oversegmentation (bucket capacity 15616, 448 hoods, 256 regions),
-the bucket ``chip_smoke.py`` runs the fused EM tick at.
+the bucket ``chip_smoke.py`` runs the fused EM tick at; and, for the
+neighbourhood program and the ``static`` EM executable, the bucket of the
+full 1813x1830 slice (``bench/configs/fullslice1813x1830-g227x229.json``).
 
 The topology is described inside a module-scoped fixture, never at import
 time: only one process at a time may load the TPU library, and every test
@@ -18,12 +20,18 @@ worker imports every test file.
 """
 
 import functools
+import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import api
+from repro.api import session as session_mod
+from repro.core.pmrf import em as em_mod
+from repro.core.pmrf import hoods as hoods_mod
 from repro.kernels import em_tick, map_step, segment_reduce
 
 H, N_HOODS, N_VERTICES = 15616, 448, 257
@@ -86,3 +94,43 @@ def test_segment_reduce_compiles_for_v5e_at_1024_segments(one_chip, op):
     )
     args = [((H,), jnp.float32), ((H,), jnp.int32)]
     assert _kernel_lines(_compile_text(fn, args, one_chip), "segment_reduce")
+
+
+FULL_SLICE = (pathlib.Path(__file__).resolve().parents[1] / "bench" / "configs"
+              / "fullslice1813x1830-g227x229.json")
+
+
+def _full_slice():
+    cfg = json.loads(FULL_SLICE.read_text())
+    return cfg, session_mod.BucketKey(*cfg["bucket"]["value"])
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
+
+
+def test_hood_program_compiles_for_v5e_at_the_full_slice_class(one_chip):
+    # The class every derived full slice lands in: key lanes and the padded
+    # CSR at the bucket capacity, the cliques at its hood count.
+    cfg, bucket = _full_slice()
+    gy, gx = cfg["overseg_grid"]
+    i32 = jnp.int32
+    shapes = [((bucket.capacity,), i32)] * 2 + [
+        ((gy * gx + 1,), i32), ((bucket.capacity,), i32), ((), i32), ((), i32)]
+    specs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = hoods_mod._hood_arrays.lower(
+        *specs, n_cliques=bucket.n_hoods, n_regions=gy * gx,
+        n_lanes=bucket.capacity).compile()
+    assert "sort" in compiled.as_text()
+
+
+def test_static_em_compiles_for_v5e_at_the_full_slice_bucket(one_chip):
+    cfg, bucket = _full_slice()
+    inputs = _on(one_chip, session_mod._abstract_inputs(bucket, None, 1, cfg["n_labels"]))
+    em_config = api.ExecutionConfig(
+        mode=cfg["mode"], max_em_iters=cfg["max_em_iters"],
+        max_map_iters=cfg["max_map_iters"]).em_config(backend="pallas-tpu")
+    compiled = em_mod.run_em.lower(*inputs, em_config).compile()
+    # About 1.4 GB of temporaries at a full slice, of the chip's 16 GB.
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
