@@ -55,9 +55,8 @@ def _trace_driver(spec: registry.DriverSpec, mode: str, backend: str, k: int):
             bucket, registry.AUDIT_BATCH, 1, k
         )
         state = sess._abstract_tick_state(bucket, registry.AUDIT_BATCH, k)
-        vplan = sess._abstract_vote_plan(bucket, registry.AUDIT_BATCH)
         traced = em_mod.run_em_ticked.trace(
-            hoods, model, state, vplan, emc, registry.AUDIT_TICK_ITERS
+            hoods, model, state, emc, registry.AUDIT_TICK_ITERS
         )
     else:
         batch = registry.AUDIT_BATCH if spec.batched else None

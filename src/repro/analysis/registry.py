@@ -59,7 +59,7 @@ class DriverSpec:
 
     name: str           # "run_em" | "run_em_batched" | "run_em_ticked"
     batched: bool       # takes a leading batch axis
-    ticked: bool        # takes (hoods, model, TickState, TickVotePlan)
+    ticked: bool        # takes (hoods, model, TickState)
 
 
 DRIVERS: Tuple[DriverSpec, ...] = (
@@ -91,22 +91,22 @@ KERNEL_NAMES: Tuple[str, ...] = (
 # ---------------------------------------------------------------------------
 # JX005 loop-census budgets.
 #
-# Measured lowerings (jax 0.4.37, CPU trace at the aligned AUDIT_BUCKET
-# shapes), maxed over K in {2, 3, 5}:
-#   - faithful: 8 scatters from the paper-faithful sort/compact pipeline
-#     (incl. the per-element scatter-min) + 3 label/reseed .at[].set's.
-#   - static: the keyed reductions lower to scatter-adds; the ticked
-#     pool path replaces integer-count scatters with run-boundary
-#     gathers, so its scatter count DROPS and its gather count grows as
-#     6*K+3 (K-1 unrolled count passes + K-1 vote passes + the
-#     loop-invariant totals; the last label of each comes from the exact
-#     integer complement, DESIGN.md §17) — 33 at K=5.
+# Measured lowerings (CPU trace at the aligned AUDIT_BUCKET shapes), maxed
+# over K in {2, 3, 5}:
+#   - static (every driver, the ticked pool included): the MAP iteration
+#     reduces over the plan's sorted runs (DESIGN.md §13), so the loops'
+#     scatters are the M-step's three label-keyed segment sums plus two
+#     .at[].set's, and the gathers are labels[vertex], the hood-end read,
+#     the argmin in vertex order and K-1 reads at the vertex bounds — 7 at
+#     K=5.
+#   - faithful: the same, plus the sort pipeline's per-element scatter-min
+#     and its replication gather.
 #   - static-pallas: the fused EM-tick route (DESIGN.md §16) folds the
-#     per-label count pass into the launch, so the per-label cnt_e pad
-#     writes of the old two-launch composition are gone.  At the audit
-#     bucket the one-hot VMEM guard routes the tick to the xla reference
-#     composition, whose compound-key count reduction is K-independent —
-#     9 scatters flat over K (10 ticked: one extra pool .at[].set).
+#     per-label count pass into the launch.  At the audit bucket the
+#     one-hot VMEM guard routes the tick to the xla reference composition,
+#     whose compound-key count reduction is K-independent — 9 scatters flat
+#     over K.  Every ticked driver hoists the slots' context out of its
+#     loop, so its census is the serial driver's.
 # The two backends lower identically at aligned shapes (the interpret
 # flag changes execution, not the traced program), so each mode's row is
 # duplicated per backend.  A combo missing from this table gets budget
@@ -114,15 +114,15 @@ KERNEL_NAMES: Tuple[str, ...] = (
 # sentinel can't gate it.
 # ---------------------------------------------------------------------------
 _MODE_BUDGETS: Dict[Tuple[str, str], Dict[str, int]] = {
-    ("run_em", "faithful"): {"scatter": 11, "gather": 7},
-    ("run_em_batched", "faithful"): {"scatter": 11, "gather": 7},
-    ("run_em_ticked", "faithful"): {"scatter": 11, "gather": 7},
-    ("run_em", "static"): {"scatter": 10, "gather": 6},
-    ("run_em_batched", "static"): {"scatter": 10, "gather": 6},
-    ("run_em_ticked", "static"): {"scatter": 7, "gather": 33},
+    ("run_em", "faithful"): {"scatter": 6, "gather": 7},
+    ("run_em_batched", "faithful"): {"scatter": 6, "gather": 7},
+    ("run_em_ticked", "faithful"): {"scatter": 6, "gather": 7},
+    ("run_em", "static"): {"scatter": 5, "gather": 7},
+    ("run_em_batched", "static"): {"scatter": 5, "gather": 7},
+    ("run_em_ticked", "static"): {"scatter": 5, "gather": 7},
     ("run_em", "static-pallas"): {"scatter": 9, "gather": 2},
     ("run_em_batched", "static-pallas"): {"scatter": 9, "gather": 2},
-    ("run_em_ticked", "static-pallas"): {"scatter": 10, "gather": 5},
+    ("run_em_ticked", "static-pallas"): {"scatter": 9, "gather": 2},
 }
 
 _LOOP_BUDGETS: Dict[Tuple[str, str, str], Dict[str, int]] = {

@@ -195,6 +195,8 @@ def _abstract_inputs(
         rep_test_label=arr((2 * cap,), jnp.int32),
         rep_hood_id=arr((2 * cap,), jnp.int32),
         rep_valid=arr((2 * cap,), jnp.bool_),
+        vote_perm=arr((cap,), jnp.int32),
+        vote_bounds=arr((nr + 2,), jnp.int32),
     )
     model = energy_mod.EnergyModel(
         region_mean=arr((nr + 1,), jnp.float32),
@@ -233,14 +235,6 @@ def _abstract_tick_state(bucket: BucketKey, batch: int, n_labels: int = 2):
         map_total=arr((), jnp.int32),
         done=arr((), jnp.bool_),
         status=arr((), jnp.int32),
-    )
-
-
-def _abstract_vote_plan(bucket: BucketKey, batch: int):
-    cap, _, nr = bucket
-    return em_mod.TickVotePlan(
-        perm=jax.ShapeDtypeStruct((batch, cap), jnp.int32),
-        bounds=jax.ShapeDtypeStruct((batch, nr + 2), jnp.int32),
     )
 
 
@@ -559,9 +553,8 @@ class Segmenter:
             em_config = self.config.em_config(backend=bk)
             hoods_abs, model_abs, *_ = _abstract_inputs(bucket, batch, 1, n_labels)
             state_abs = _abstract_tick_state(bucket, batch, n_labels)
-            plan_abs = _abstract_vote_plan(bucket, batch)
             compiled = em_mod.run_em_ticked.lower(
-                hoods_abs, model_abs, state_abs, plan_abs, em_config, tick_iters
+                hoods_abs, model_abs, state_abs, em_config, tick_iters
             ).compile()
             return compiled, em_config
 
@@ -569,10 +562,9 @@ class Segmenter:
 
     def ticked_pool(self, target, *, batch: int):
         """An all-empty slot pool for a ticked executable — ``(hoods,
-        model, state, vote_plan)`` with blank (sentinel) hoods/model lanes,
-        ``em.blank_tick_state`` (every lane ``done``, ready for admission)
-        and the matching blank vote plans.  Shapes match
-        :meth:`compile_ticked`'s abstract inputs exactly."""
+        model, state)`` with blank (sentinel) hoods/model lanes and
+        ``em.blank_tick_state`` (every lane ``done``, ready for admission).
+        Shapes match :meth:`compile_ticked`'s abstract inputs exactly."""
         bucket = BucketKey(*(target.bucket if isinstance(target, Plan) else target))
         cap, nh, nr = bucket
         n_labels = self.config.n_labels
@@ -593,6 +585,13 @@ class Segmenter:
             rep_test_label=full((2 * cap,), 0, jnp.int32),
             rep_hood_id=full((2 * cap,), nh, jnp.int32),
             rep_valid=full((2 * cap,), False, jnp.bool_),
+            # every lane invalid: vertex order is lane order, all in the
+            # sentinel region's run
+            vote_perm=jnp.broadcast_to(jnp.arange(cap, dtype=jnp.int32), (batch, cap)),
+            vote_bounds=jnp.broadcast_to(
+                jnp.where(jnp.arange(nr + 2) == nr + 1, cap, 0).astype(jnp.int32),
+                (batch, nr + 2),
+            ),
         )
         model = energy_mod.EnergyModel(
             region_mean=full((nr + 1,), 0.0, jnp.float32),
@@ -603,8 +602,7 @@ class Segmenter:
             reseed_sigma=full((), 1.0, jnp.float32),
         )
         state = em_mod.blank_tick_state(batch, nh, nr, n_labels)
-        vote_plan = jax.vmap(lambda v: em_mod.make_vote_plan(v, nr))(hoods.vertex)
-        return hoods, model, state, vote_plan
+        return hoods, model, state
 
     def lane_inputs(
         self, plan: Plan, *, bucket: Optional[BucketKey] = None, seed: int = 0
@@ -620,11 +618,10 @@ class Segmenter:
     def lane_state(
         self, plan: Plan, *, bucket: Optional[BucketKey] = None, seed: int = 0
     ):
-        """One request's admission-ready lane: ``(hoods, model, lane_state,
-        vote_plan)``, i.e. :meth:`lane_inputs` with the per-lane
-        :class:`em.TickState` and :class:`em.TickVotePlan` already built.
-        Memoized per plan alongside the padding (§17): the argsort behind
-        the vote plan and the initial lane state are pure functions of the
+        """One request's admission-ready lane: ``(hoods, model,
+        lane_state)``, i.e. :meth:`lane_inputs` with the per-lane
+        :class:`em.TickState` already built.  Memoized per plan alongside
+        the padding (§17): the initial lane state is a pure function of the
         padded inputs, so steady-state admission pays zero host-side
         recomputation for repeat traffic."""
         bucket = BucketKey(*bucket) if bucket is not None else plan.bucket
@@ -633,13 +630,12 @@ class Segmenter:
             "lane", bucket, seed, self.config.init, self.config.shards,
             self.config.n_labels,
         )
-        cached = plan._padded.get(memo_key)
-        if cached is None:
-            lane = em_mod.init_tick_lane(lab0, mu0, sig0, bucket.n_hoods)
-            vplan = em_mod.make_vote_plan(h1.vertex, bucket.n_regions)
-            cached = plan._padded[memo_key] = (lane, vplan)
-        lane, vplan = cached
-        return h1, m1, lane, vplan
+        lane = plan._padded.get(memo_key)
+        if lane is None:
+            lane = plan._padded[memo_key] = em_mod.init_tick_lane(
+                lab0, mu0, sig0, bucket.n_hoods
+            )
+        return h1, m1, lane
 
     def clear_cache(self) -> None:
         self._cache.clear()

@@ -150,9 +150,90 @@ def blocked_scan(x: Array, op: str = "add") -> Array:
     rows = -(-n // SCAN_BLOCK)
     blocks = jnp.pad(x, (0, rows * SCAN_BLOCK - n), constant_values=identity)
     inner = cum(blocks.reshape(rows, SCAN_BLOCK), axis=1)
-    totals = blocked_scan(inner[:, -1], op)
+    totals = blocked_scan(_row_last(inner), op)
     before = jnp.concatenate([jnp.full((1,), identity, x.dtype), totals[:-1]])
     return combine(inner, before[:, None]).reshape(-1)[:n]
+
+
+def _row_last(x: Array) -> Array:
+    """``x[:, -1]`` as a slice (indexing lowers to a gather under ``vmap``)."""
+    return jax.lax.index_in_dim(x, x.shape[1] - 1, axis=1, keepdims=False)
+
+
+def segmented_scan(
+    values: Array, run_start: Array, op: str = "add", *, reverse: bool = False
+) -> Array:
+    """Inclusive segmented scan of a 1-D array over runs of lanes.
+
+    Lane ``i`` begins a new run where ``run_start[i]`` is true; lane 0
+    always does.  ``op="add"`` gives each lane the sum of its run up to
+    and including itself; ``op="copy"`` gives it the value at its run's
+    first lane.  ``reverse=True`` scans from the last lane over the same
+    runs, so ``add`` sums to the run's end and ``copy`` gives every lane
+    its run's last value.
+
+    Blocked like :func:`blocked_scan`: a log-step scan within rows of
+    :data:`SCAN_BLOCK` lanes, a segmented scan of the row carries, and one
+    combine.  The order of summation depends only on the length and the
+    flags, never on the platform's lowering, so a batched (``vmap``) scan
+    gives every row the bits of the unbatched one.  Sums of integer-valued
+    floats below 2**24 are exact in any order.
+    """
+    if op not in ("add", "copy"):
+        raise ValueError(f"unknown segmented_scan op: {op}")
+
+    def run():
+        start = run_start.astype(bool)
+        if not reverse:
+            return _segmented_scan(values, start, op)
+        # Read backwards, each run's last lane is its first.
+        end = jnp.concatenate([start[1:], jnp.ones((1,), bool)])
+        return _segmented_scan(values[::-1], end[::-1], op)[::-1]
+
+    return _timed("SegmentedScan", run)
+
+
+def _segmented_scan(values: Array, start: Array, op: str) -> Array:
+    n = values.shape[0]
+    start = start | (jnp.arange(n) == 0)
+    if n <= SCAN_BLOCK:
+        return _row_segmented_scan(start[None], values[None], op)[1][0]
+    rows = -(-n // SCAN_BLOCK)
+    pad = rows * SCAN_BLOCK - n
+    flags = jnp.pad(start, (0, pad), constant_values=True)
+    vals = jnp.pad(values, (0, pad))
+    seen, inner = _row_segmented_scan(
+        flags.reshape(rows, SCAN_BLOCK), vals.reshape(rows, SCAN_BLOCK), op
+    )
+    # Each row's last lane carries into the next row's lanes before that
+    # row's first run start.
+    carry = _segmented_scan(_row_last(inner), _row_last(seen), op)
+    carry_in = jnp.concatenate([jnp.zeros((1,), values.dtype), carry[:-1]])[:, None]
+    joined = carry_in + inner if op == "add" else jnp.broadcast_to(carry_in, inner.shape)
+    return jnp.where(seen, inner, joined).reshape(-1)[:n]
+
+
+def _row_segmented_scan(flags: Array, vals: Array, op: str) -> Tuple[Array, Array]:
+    """Segmented inclusive scan along the last axis in log2(width) steps
+    (Hillis-Steele): step ``d`` folds in the lane ``d`` to the left unless
+    a run starts between.  Returns (a run start seen so far, the scan)."""
+    width = vals.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, vals.shape, vals.ndim - 1)
+
+    def shifted(x, d):
+        return jnp.concatenate(
+            [jnp.zeros(x.shape[:-1] + (d,), x.dtype), x[..., : width - d]], axis=-1
+        )
+
+    d = 1
+    while d < width:
+        has = lane >= d
+        take = has & ~flags
+        prev = shifted(vals, d)
+        vals = jnp.where(take, prev + vals if op == "add" else prev, vals)
+        flags = flags | (has & shifted(flags, d))
+        d *= 2
+    return flags, vals
 
 
 def scan_(values: Array, *, exclusive: bool = False, axis: int = 0) -> Array:
@@ -459,6 +540,7 @@ __all__ = [
     "reduce_by_key",
     "unique_",
     "blocked_scan",
+    "segmented_scan",
     "counts_to_offsets",
     "expand",
     "expand_with_rank",

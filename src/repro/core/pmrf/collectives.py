@@ -20,7 +20,9 @@ to single-device.
 
 :class:`ReduceCtx` carries those four hooks.  The single-device context
 (``axis=None``, the module constant :data:`LOCAL`) lowers each to the plain
-DPP primitive; the sharded context (``axis="<mesh axis>"``) wraps the local
+DPP primitive (the single-device static and faithful MAP iteration needs
+only hook 4: it reduces over the plan's sorted runs, DESIGN.md §13); the
+sharded context (``axis="<mesh axis>"``) wraps the local
 primitive in the matching ``dpp_sharded`` collective.  The driver is
 written once against the context, so ``distributed.py`` no longer forks
 the MAP/EM loop bodies — it just builds a sharded context and ``shard_map``s

@@ -45,7 +45,7 @@ from repro.core.pmrf import collectives
 from repro.core.pmrf import em as em_mod
 from repro.core.pmrf import energy as E
 from repro.core.pmrf.em import EMConfig, EMResult
-from repro.core.pmrf.hoods import Hoods
+from repro.core.pmrf.hoods import Hoods, vote_order
 
 Array = jax.Array
 
@@ -118,6 +118,7 @@ def partition_hoods(hoods: Hoods, n_shards: int) -> Hoods:
         out_hood[pos] = rep_hood[lanes]
         out_valid[pos] = True
 
+    vote_perm, vote_bounds = vote_order(vertex, n_regions)
     return Hoods(
         vertex=jnp.asarray(vertex),
         hood_id=jnp.asarray(hood_id),
@@ -131,6 +132,8 @@ def partition_hoods(hoods: Hoods, n_shards: int) -> Hoods:
         rep_test_label=jnp.asarray(out_test),
         rep_hood_id=jnp.asarray(out_hood),
         rep_valid=jnp.asarray(out_valid),
+        vote_perm=jnp.asarray(vote_perm),
+        vote_bounds=jnp.asarray(vote_bounds),
     )
 
 
@@ -195,6 +198,9 @@ def run_em_sharded(
             rep_test_label=rep_test,
             rep_hood_id=rep_hood,
             rep_valid=rep_valid,
+            # unused by the driver: the sharded route votes by scatter
+            vote_perm=jnp.zeros_like(vertex),
+            vote_bounds=jnp.zeros((n_regions + 2,), jnp.int32),
         )
         lmodel = E.EnergyModel(*model_arrays)
         return em_mod._em_driver(local, lmodel, labels0, mu0, sigma0, config, ctx)

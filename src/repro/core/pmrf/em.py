@@ -17,7 +17,9 @@ There is exactly ONE driver (:func:`_em_driver`), parametrized by a
 collective context (``collectives.ReduceCtx``, DESIGN.md §11): the four
 cross-element touch points — per-hood label counts, per-hood energy sums,
 the label-vote scatter, and the convergence AND — go through the context's
-hooks.  :func:`run_em` binds the single-device context;
+hooks.  On one device the first three are reductions over the plan's
+sorted runs instead (``energy.map_step_runs``, DESIGN.md §13); the sharded
+context keeps its keyed scatters.  :func:`run_em` binds the single-device context;
 ``distributed.run_em_sharded`` builds a sharded context and ``shard_map``s
 the same driver, so multi-device execution is a parametrization, not a
 fork.
@@ -91,6 +93,12 @@ OK_STATUSES = frozenset({STATUS_CONVERGED, STATUS_MAX_ITERS})
 TRACE_COUNTS = budget_mod.LEDGER.section(
     "trace", keys=("run_em", "run_em_batched", "run_em_sharded", "run_em_ticked")
 )
+
+
+# Which reductions a MAP iteration was traced with: over the plan's sorted
+# runs (every single-device unfused route) or by keyed scatter (the
+# sharded context, whose hoods span shards).  Bumped at trace time.
+budget_mod.LEDGER.section("em", keys=("run_reduce_traces", "scatter_reduce_traces"))
 
 
 def reset_trace_counts() -> None:
@@ -213,7 +221,10 @@ def _map_step(
     ONE fused launch (``E.em_tick_fused``, DESIGN.md §16) and the carry's
     ``msums`` holds the kernel's M-step sums for the EM boundary.  The
     sharded static-pallas route keeps ``E.map_step_fused`` (its collectives
-    interleave with the kernel's stages); everything else is unchanged."""
+    interleave with the kernel's stages).  The single-device static and
+    faithful routes reduce over the plan's sorted runs
+    (``E.map_step_runs``; every lane's reductions are its own, so they need
+    no mask); the sharded ones by keyed scatter, psum'd."""
     n_labels = int(mu.shape[0])
     fused_tick = mode == "static-pallas" and not ctx.sharded
     conv_raw = None
@@ -226,11 +237,19 @@ def _map_step(
         )
         msums = jnp.stack([sum_w, sum_wy, sum_wyy])
     elif mode == "static-pallas":
+        budget_mod.LEDGER.bump("em", "scatter_reduce_traces")
         labels, hood_e = E.map_step_fused(
             hoods, model, sctx, carry.labels, mu, sigma, backend=backend, ctx=ctx,
             active=active,
         )
+    elif not ctx.sharded:
+        budget_mod.LEDGER.bump("em", "run_reduce_traces")
+        labels, hood_e = E.map_step_runs(
+            hoods, model, sctx, carry.labels, mu, sigma,
+            faithful=mode == "faithful", backend=backend,
+        )
     else:
+        budget_mod.LEDGER.bump("em", "scatter_reduce_traces")
         # backend selects the keyed-reduction lowering here too; the vote
         # scatter stays on XLA (scatter_ has no pallas lowering).  The
         # neighborhood counts go through the collective context so sharded
@@ -351,9 +370,11 @@ def _em_driver(
     # will not retrace.
     kops.resolve_backend(config.backend)  # validate early: raises on unknown
     backend = config.backend
+    # The sharded static and faithful routes hoist nothing: their keyed
+    # scatters compute every reduction in the loop.
     sctx = (
         E.make_static_context(hoods, model, backend=backend, ctx=ctx)
-        if mode == "static-pallas"
+        if mode == "static-pallas" or not ctx.sharded
         else None
     )
 
@@ -679,180 +700,40 @@ def _tick_micro(
     return jax.tree.map(lambda new, old: jnp.where(s.done, old, new), stepped, s)
 
 
-class TickVotePlan(NamedTuple):
-    """Loop-invariant vertex-run structure for the pool-form micro-step.
-
-    Per lane, ``perm`` stably sorts the hood elements by vertex id and
-    ``bounds[k]`` is the first sorted position with vertex >= k — so any
-    per-vertex integer-count reduction (the label votes) becomes a gather
-    + cumulative-sum + run-boundary difference instead of a 65k-element
-    scatter.  Both arrays depend only on the neighborhood structure, so
-    they are computed once per admission (``make_vote_plan``), never per
-    micro-step.
-    """
-
-    perm: Array    # (cap,) int32 — stable argsort of vertex within the lane
-    bounds: Array  # (n_regions + 2,) int32 — run boundaries in sorted order
-
-
-@partial(jax.jit, static_argnames=("n_regions",))
-def make_vote_plan(vertex: Array, n_regions: int) -> TickVotePlan:
-    """Build one lane's :class:`TickVotePlan` from its vertex array."""
-    perm = jnp.argsort(vertex, stable=True).astype(jnp.int32)
-    sorted_v = jnp.take_along_axis(vertex, perm, axis=-1)
-    bounds = jnp.searchsorted(
-        sorted_v, jnp.arange(n_regions + 2, dtype=vertex.dtype)
-    ).astype(jnp.int32)
-    return TickVotePlan(perm=perm, bounds=bounds)
-
-
-def _run_sums(values: Array, bounds: Array) -> Array:
-    """Per-run sums of ``values`` (B, cap) along contiguous runs delimited
-    by ``bounds`` (B, K+1): ``out[:, k] = sum(values[:, bounds[k]:bounds[k+1]])``
-    via cumulative sum + boundary difference.
-
-    EXACT (bitwise order-independent) for integer-valued float inputs with
-    totals below 2^24 — which is every use here: label counts, hood sizes,
-    and votes are all 0/1 sums bounded by the lane capacity.  Never use it
-    for real-valued energies (the boundary subtraction would trade the
-    scatter's sequential rounding for catastrophic cancellation).
-    """
-    cum = jnp.cumsum(values, axis=1)
-    cum0 = jnp.concatenate(
-        [jnp.zeros((values.shape[0], 1), values.dtype), cum], axis=1
-    )
-    return jnp.take_along_axis(cum0, bounds[:, 1:], axis=1) - jnp.take_along_axis(
-        cum0, bounds[:, :-1], axis=1
-    )
-
-
 def _pool_tick_micro(
     hoods: Hoods,
     model: E.EnergyModel,
-    vote_plan: TickVotePlan,
+    sctx: E.StaticMapContext,
     backend: str,
     config: EMConfig,
     s: TickState,
 ) -> TickState:
-    """One masked micro-step for the WHOLE pool in flat DPP form (static
-    mode's fast path).
+    """One masked micro-step for the WHOLE pool (static mode's path).
 
-    ``jax.vmap`` of the per-lane step lowers the keyed reductions to
-    batched scatters, which XLA:CPU executes far worse than the serial
-    driver's flat ones (measured ~3x per lane-step — the ticked engine
-    would inherit exactly the inversion it exists to fix).  The pool is
-    really just one bigger DPP problem, so this path treats it as one
-    (the paper's own flatten-and-reduce idiom applied to the slot axis),
-    and exploits structure the while_loop drivers get from XLA for free:
+    The MAP iteration is the serial driver's own (``E.map_step_runs``
+    vmapped over the slots; its runs are lane-isolated and it scatters
+    nothing), so every lane's labels and hood energies are bitwise the
+    serial ones.  ``sctx`` is the slots' hoisted context, computed once per
+    tick outside its loop.
 
-    * label-independent quantities (hood sizes, vote denominators) are
-      loop-invariant and left unmasked so XLA hoists them out of the tick
-      loop — masking them would drag them into every micro-step;
-    * integer-valued keyed reductions (label counts, votes) are computed
-      by cumulative-sum + run-boundary difference over their sorted key
-      runs (``hoods.offsets`` for hood ids, :class:`TickVotePlan` for
-      vertex ids) — exact for integer counts, and ~10x cheaper than the
-      equivalent scatter on CPU;
-    * only the real-valued hood ENERGY sums go through the
-      order-preserving flat ``segment_sum`` (lane-offset key space), so
-      their per-segment accumulation order — and with it the bit-identity
-      contract — matches the per-lane step exactly.
-
-    Arithmetic is a transcription of ``_map_step`` (static mode) +
-    ``update_parameters`` + the `_tick_micro` boundary selects onto
-    batched arrays; modes with per-lane sorts or kernel launches
-    (faithful, static-pallas) keep the vmapped lane path.
+    The EM boundary's M-step is flattened instead: ``jax.vmap`` of the
+    per-lane label-keyed segment sums lowers to batched scatters, which
+    XLA:CPU executes far worse than flat ones (measured ~3x per lane-step),
+    so the slot axis folds into one lane-offset key space.  Arithmetic is a
+    transcription of ``update_parameters`` + the `_tick_micro` boundary
+    selects onto batched arrays; modes with per-lane sorts or kernel
+    launches (faithful, static-pallas) keep the vmapped lane path.
     """
+    budget_mod.LEDGER.bump("em", "run_reduce_traces")
     B = s.labels.shape[0]
     K = int(s.mu.shape[1])
-    nh, nr = hoods.n_hoods, hoods.n_regions
     lane = jnp.arange(B, dtype=jnp.int32)
     active = ~s.done                                   # (B,)
-    hid_flat = (hoods.hood_id + lane[:, None] * (nh + 1)).reshape(-1)
-
-    def seg_sum_hood(values):                          # (B, cap) -> (B, nh+1)
-        return dpp.reduce_by_key(
-            hid_flat, values.reshape(-1), B * (nh + 1), op="add",
-            backend=backend,
-        ).reshape(B, nh + 1)
-
-    def count_by_hood(values):                         # (B, cap) -> (B, nh+1)
-        # Valid elements sit packed at the lane front in ascending hood_id
-        # runs delimited by hoods.offsets; padding beyond the packed region
-        # only ever lands in the sentinel segment, whose value is never
-        # read (padding elements are weight-0 everywhere downstream).
-        runs = _run_sums(values, hoods.offsets)
-        return jnp.concatenate([runs, jnp.zeros((B, 1), values.dtype)], axis=1)
-
-    def count_by_vertex(values):                       # (B, cap) -> (B, nr+1)
-        gathered = jnp.take_along_axis(values, vote_plan.perm, axis=1)
-        return _run_sums(gathered, vote_plan.bounds)
-
-    # --- one MAP iteration (== _map_step, static mode) -----------------
-    valid = hoods.valid
-    validf = valid.astype(jnp.float32)
-    x = jnp.take_along_axis(s.labels, hoods.vertex, axis=1)
-    # Per-(hood, label) counts: K-1 run-sum passes over the hood runs plus
-    # one complement — counts are integer-valued floats far below 2^24, so
-    # ``cnt[0] = nall - sum(cnt[1:])`` is exact, and the K=2 instance
-    # collapses back to the original binary path's single n1 pass (the
-    # PR 5 K-ary generalization paid K passes here and one more per label
-    # in the vote scatter; that was the measured +33% per-micro-step
-    # regression in BENCH_serve — DESIGN.md §17).  Lane activity masks are
-    # *omitted* on these reductions: every keyed reduction is lane-isolated
-    # (lane-offset key spaces / per-lane run sums), and the final freeze
-    # select discards frozen lanes' values, so masking bought nothing but
-    # prevented XLA from hoisting the loop-invariant totals.
-    eqs = [(x == l).astype(jnp.float32) for l in range(K)]
-    nall = count_by_hood(validf)                       # loop-invariant
-    nall_e_full = jnp.take_along_axis(nall, hoods.hood_id, axis=1)
-    cnt_rest = [
-        jnp.take_along_axis(
-            count_by_hood(validf * eqs[l]), hoods.hood_id, axis=1
+    new_labels, hood_e = jax.vmap(
+        lambda h, m, c, lab, mu, sigma: E.map_step_runs(
+            h, m, c, lab, mu, sigma, backend=backend
         )
-        for l in range(1, K)
-    ]
-    cnt0 = nall_e_full - sum(cnt_rest) if K > 1 else nall_e_full
-    cnt_e = [cnt0] + cnt_rest
-
-    y = jnp.take_along_axis(model.region_mean, hoods.vertex, axis=1)
-    w = jnp.take_along_axis(model.region_weight, hoods.vertex, axis=1) * validf
-    sig = jnp.maximum(s.sigma, model.sigma_min[:, None])   # (B, K)
-    nall_e = nall_e_full
-    denom = jnp.maximum(nall_e - 1.0, 1.0)
-    beta = model.beta[:, None]
-
-    def data_term(l):
-        d = y - s.mu[:, l][:, None]
-        sl = sig[:, l][:, None]
-        return w * (d * d / (2.0 * sl * sl) + jnp.log(sl))
-
-    # (nall - cnt_l) - (1 - [x == l]): integer-exact, so K=2 is bitwise the
-    # historical n1-based pair of expressions (DESIGN.md §13).
-    es = [
-        data_term(l) + beta * jnp.maximum(
-            (nall_e - cnt_e[l]) - (1.0 - eqs[l]), 0.0
-        ) / denom * validf
-        for l in range(K)
-    ]
-    energies = jnp.stack(es)                            # (K, B, cap)
-    min_e = jnp.min(energies, axis=0)
-    arg = jnp.argmin(energies, axis=0).astype(jnp.int32)   # ties -> lowest
-    hood_e = seg_sum_hood(jnp.where(valid, min_e, 0.0))[:, :nh]
-    # Votes: K-1 passes + the loop-invariant total (every valid element
-    # casts exactly one vote, so the last label's tally is the exact
-    # integer complement — same trick as the counts above).
-    votes_all = count_by_vertex(validf)                 # loop-invariant
-    votes_rest = [
-        count_by_vertex(jnp.where(valid, (arg == l).astype(jnp.float32), 0.0))
-        for l in range(K - 1)
-    ]
-    votes_last = (
-        votes_all - sum(votes_rest) if K > 1 else votes_all
-    )
-    votes = jnp.stack(votes_rest + [votes_last])        # (K, B, nr+1)
-    new_labels = jnp.argmax(votes, axis=0).astype(jnp.int32)  # plurality
-    new_labels = new_labels.at[:, nr].set(0)
+    )(hoods, model, sctx, s.labels, s.mu, s.sigma)
 
     map_hist = jnp.roll(s.map_hist, shift=1, axis=1).at[:, 0].set(hood_e)
     map_i = s.map_i + 1
@@ -860,9 +741,9 @@ def _pool_tick_micro(
     scale = jnp.maximum(jnp.abs(map_hist[:, 0]), 1.0)
     conv = jnp.all(deltas < CONV_TOL * scale[:, None], axis=1)     # (B, nh)
     # Divergence (== _map_step): non-finite lane energies exit the inner
-    # loop this micro-step.  Lanes are isolated in every keyed reduction
-    # (lane-offset key spaces, per-lane run sums), so one lane's NaN can
-    # never leak into a co-resident healthy lane.
+    # loop this micro-step.  Lanes are isolated in every reduction (per-lane
+    # runs, lane-offset key spaces), so one lane's NaN can never leak into
+    # a co-resident healthy lane.
     bad = ~jnp.all(jnp.isfinite(hood_e), axis=1)                   # (B,)
     map_done = jnp.where(
         active,
@@ -950,7 +831,6 @@ def run_em_ticked(
     hoods: Hoods,
     model: E.EnergyModel,
     state: TickState,
-    vote_plan: TickVotePlan,
     config: EMConfig = EMConfig(),
     tick_iters: int = 8,
 ) -> tuple[TickState, Array]:
@@ -958,9 +838,7 @@ def run_em_ticked(
     tick); returns ``(state, steps_executed)``.
 
     All inputs carry a leading slot axis (the pool's ``max_batch``); static
-    ``Hoods`` fields must hold the pool's shared bucket values, and
-    ``vote_plan`` the per-lane vertex-run structure (``make_vote_plan``,
-    written at admission alongside the lane's hoods).  Lanes with
+    ``Hoods`` fields must hold the pool's shared bucket values.  Lanes with
     ``state.done`` are frozen, so the host can retire them and write fresh
     requests into their slots between ticks without disturbing in-flight
     lanes — and without retracing, because the pool's shapes never change
@@ -989,25 +867,22 @@ def run_em_ticked(
     kops.resolve_backend(config.backend)  # validate early: raises on unknown
     mode, backend = config.mode, config.backend
 
+    # The slots' hoisted context, once per tick, outside its loop.
+    sctx = jax.vmap(lambda h, m: E.make_static_context(h, m, backend=backend))(
+        hoods, model
+    )
     if mode == "static":
-        # Flat pool-form fast path: one DPP problem, no batched scatters.
+        # Pool form: the M-step flattened over the slot axis.
         def micro(st):
-            return _pool_tick_micro(hoods, model, vote_plan, backend, config, st)
+            return _pool_tick_micro(hoods, model, sctx, backend, config, st)
     else:
         # faithful / static-pallas: per-lane sorts and kernel launches
         # don't flatten across the slot axis — vmap the lane step.
-        def lane(h, m, s):
-            sctx = (
-                E.make_static_context(h, m, backend=backend)
-                if mode == "static-pallas"
-                else None
-            )
-            return _tick_micro(
-                h, m, mode, backend, sctx, collectives.LOCAL, config, s
-            )
+        def lane(h, m, c, s):
+            return _tick_micro(h, m, mode, backend, c, collectives.LOCAL, config, s)
 
         def micro(st):
-            return jax.vmap(lane)(hoods, model, st)
+            return jax.vmap(lane)(hoods, model, sctx, st)
 
     def cond(carry):
         i, st = carry

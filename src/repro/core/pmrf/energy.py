@@ -19,9 +19,10 @@ The paper's binary PMRF is the K=2 instance, and the K=2 results are
 bit-identical to the historical binary implementation: every K-ary
 rewrite below only touches integer-valued quantities (counts, votes),
 whose float arithmetic is exact, so argmins/votes/labels are unchanged.
-Per-hood label counts and label votes fold K into the existing keyed
-reductions via ``dpp.compound_key`` — no new scatter launches per
-iteration, the key spaces just widen by a factor of K.
+On one device, per-hood label counts and label votes are K-1 passes over
+the plan's sorted runs plus an exact complement (:func:`map_step_runs`);
+the sharded route folds K into its keyed scatters via
+``dpp.compound_key``, the key spaces widening by a factor of K.
 
 Three execution modes (DESIGN.md §2, the baseline-vs-optimized axis):
 
@@ -30,8 +31,8 @@ Three execution modes (DESIGN.md §2, the baseline-vs-optimized axis):
   make label pairs adjacent -> ReduceByKey(Min) -> ReduceByKey(Add).
 * ``static``  — beyond-paper TPU mode: the neighborhood structure is
   EM-invariant, so the sort is hoisted out of the loop entirely; energies
-  are laid out (2, H) and the per-element min is a reshape-free axis-min,
-  the per-hood sum a segment-sum with precomputed ids.
+  are laid out (K, H) and the per-element min is a reshape-free axis-min,
+  the per-hood sums segmented scans over the plan's ``hood_id`` runs.
 * ``static-pallas`` — the static mode taken to the kernel level
   (DESIGN.md §3): every EM-invariant quantity (neighborhood sizes, vote
   denominators, gathered region stats) is hoisted into a
@@ -139,14 +140,22 @@ def label_energies(
     w = model.region_weight[v] * hoods.valid.astype(jnp.float32)
     x = labels[v]
 
-    sig = jnp.maximum(sigma, model.sigma_min)
-
     if hood_counts is None:
         counts, nall = hood_label_counts(hoods, labels, n_labels, backend=backend)
     else:
         counts, nall = hood_counts
     cnt_e = counts[hoods.hood_id]    # (H_pad, K)
     nall_e = nall[hoods.hood_id]
+    return _energies(
+        model, y, w, x, hoods.valid, [cnt_e[:, l] for l in range(n_labels)],
+        nall_e, mu, sigma,
+    )
+
+
+def _energies(model, y, w, x, valid, cnt_e, nall_e, mu, sigma) -> Array:
+    """(K, H_pad) energies from per-element operands: ``cnt_e[l]`` is the
+    count of label l in the element's hood, ``nall_e`` the hood's size."""
+    sig = jnp.maximum(sigma, model.sigma_min)
 
     # Disagreement counts are normalized by the number of *other* elements
     # in the neighborhood so beta is independent of hood size (hood sizes
@@ -160,10 +169,10 @@ def label_energies(
         d = (y - mu[l])
         data = w * (d * d / (2.0 * sig[l] * sig[l]) + jnp.log(sig[l]))
         eq = (x == l).astype(jnp.float32)
-        others_diff = (nall_e - cnt_e[:, l]) - (1.0 - eq)
-        return data + model.beta * jnp.maximum(others_diff, 0.0) / denom * hoods.valid
+        others_diff = (nall_e - cnt_e[l]) - (1.0 - eq)
+        return data + model.beta * jnp.maximum(others_diff, 0.0) / denom * valid
 
-    return jnp.stack([label_energy(l) for l in range(n_labels)])
+    return jnp.stack([label_energy(l) for l in range(int(mu.shape[0]))])
 
 
 def hood_label_counts(
@@ -393,12 +402,22 @@ class StaticMapContext(NamedTuple):
     per ``run_em`` call instead of once per MAP iteration.  (The K-ary
     plurality vote needs no hoisted denominator: argmax over per-label
     vote counts replaced the binary votes1-vs-votes_all comparison.)
+
+    The run fields describe the sorted runs the single-device MAP
+    iteration reduces over (:func:`map_step_runs`): valid elements sit
+    packed in ascending ``hood_id`` runs (``hoods.offsets``), and the
+    plan's ``vote_perm``/``vote_bounds`` order them by vertex.
     """
 
     y: Array          # (H_pad,) gathered region mean per hood element
     w: Array          # (H_pad,) gathered region weight, 0 on padding
     validf: Array     # (H_pad,) 1.0/0.0 validity mask
     nall_e: Array     # (H_pad,) neighborhood size per element
+    run_start: Array  # (H_pad,) bool: the lane starts a hood_id run
+    hood_last: Array  # (n_hoods,) int32 lane of each hood's last element
+    hood_full: Array  # (n_hoods,) bool: the hood has an element
+    valid_perm: Array # (H_pad,) bool validity in vertex order
+    votes_all: Array  # (V+1,) int32 valid elements per vertex
 
 
 def make_static_context(
@@ -410,13 +429,101 @@ def make_static_context(
 ) -> StaticMapContext:
     v = hoods.vertex
     validf = hoods.valid.astype(jnp.float32)
-    nall = ctx.segment_sum(hoods.hood_id, validf, hoods.n_hoods + 1, backend=backend)
+    hid = hoods.hood_id
+    run_start = jnp.concatenate([jnp.ones((1,), bool), hid[1:] != hid[:-1]])
+    if ctx.sharded:
+        # A hood may span shards: its size is a psum of shard partials.
+        nall = ctx.segment_sum(hid, validf, hoods.n_hoods + 1, backend=backend)
+        nall_e = nall[hid]
+    else:
+        nall_e = _run_totals(validf, run_start)
+    ends = hoods.offsets[1:]
+    valid_perm = hoods.valid[hoods.vote_perm]
     return StaticMapContext(
         y=model.region_mean[v],
         w=model.region_weight[v] * validf,
         validf=validf,
-        nall_e=nall[hoods.hood_id],
+        nall_e=nall_e,
+        run_start=run_start,
+        hood_last=jnp.maximum(ends - 1, 0),
+        hood_full=ends > hoods.offsets[:-1],
+        valid_perm=valid_perm,
+        votes_all=_vertex_run_sums(valid_perm, hoods.vote_bounds),
     )
+
+
+def _run_totals(values: Array, run_start: Array) -> Array:
+    """Each lane's run total: a forward segmented sum, then each run's last
+    lane copied back over the run."""
+    fwd = dpp.segmented_scan(values, run_start, "add")
+    return dpp.segmented_scan(fwd, run_start, "copy", reverse=True)
+
+
+def _vertex_run_sums(flags: Array, bounds: Array) -> Array:
+    """Exact per-vertex counts of ``flags`` (bool, in vertex order): an
+    integer scan read at the run bounds, so the order never matters."""
+    cum = dpp.blocked_scan(flags.astype(jnp.int32))
+    at = jnp.concatenate([jnp.zeros((1,), jnp.int32), cum])[bounds]
+    return at[1:] - at[:-1]
+
+
+def map_step_runs(
+    hoods: Hoods,
+    model: EnergyModel,
+    sctx: StaticMapContext,
+    labels: Array,
+    mu: Array,
+    sigma: Array,
+    *,
+    faithful: bool = False,
+    backend: Optional[str] = None,
+) -> Tuple[Array, Array]:
+    """One single-device MAP iteration over the plan's sorted runs ->
+    (new labels, per-hood energy sums).
+
+    No element-wide scatter: hood-keyed sums are segmented scans over the
+    ``hood_id`` runs, votes are integer run sums over the vertex order.
+    Two element-wide gathers remain: ``labels[vertex]`` and the argmin
+    permuted into vertex order.
+
+    * Counts: K-1 passes of a forward segmented sum and a reverse copy of
+      each run's total, and the complement ``nall - sum(rest)`` for label
+      0.  Counts are integer-valued floats below 2**24, so every order
+      gives the scatter's exact values and the energies bit for bit.
+    * Hood energy sums restart at each run start (a difference of two
+      prefix sums would cancel catastrophically) and are read at each
+      hood's last lane.
+    * Votes: K-1 integer run sums plus the complement of the hoisted
+      totals; ``argmax`` ties go to the lowest label.
+    """
+    n_labels = int(mu.shape[0])
+    x = labels[hoods.vertex]
+    cnt_rest = [
+        _run_totals(sctx.validf * (x == l), sctx.run_start)
+        for l in range(1, n_labels)
+    ]
+    cnt_e = [sctx.nall_e - sum(cnt_rest)] + cnt_rest
+    energies = _energies(
+        model, sctx.y, sctx.w, x, hoods.valid, cnt_e, sctx.nall_e, mu, sigma
+    )
+    if faithful:
+        min_e, arg = min_energies_faithful(hoods, energies, backend=backend)
+    else:
+        min_e, arg = min_energies_static(energies)
+    sums = dpp.segmented_scan(
+        jnp.where(hoods.valid, min_e, 0.0), sctx.run_start, "add"
+    )
+    hood_e = jnp.where(sctx.hood_full, sums[sctx.hood_last], 0.0)
+
+    arg_v = arg[hoods.vote_perm]
+    votes_rest = [
+        _vertex_run_sums((arg_v == l) & sctx.valid_perm, hoods.vote_bounds)
+        for l in range(n_labels - 1)
+    ]
+    votes = jnp.stack(votes_rest + [sctx.votes_all - sum(votes_rest)])
+    new = jnp.argmax(votes, axis=0).astype(jnp.int32)
+    sentinel = jnp.arange(hoods.n_regions + 1) == hoods.n_regions
+    return jnp.where(sentinel, 0, new), hood_e
 
 
 def map_step_fused(

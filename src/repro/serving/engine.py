@@ -302,7 +302,7 @@ class SegmentationEngine:
         self._auto_rid = 0
         self._live_rids: set = set()   # queued + resident (dropped on retire)
         self._exe = None
-        self._hoods = self._model = self._state = self._vote_plan = None
+        self._hoods = self._model = self._state = None
         self.slot_req: List[Optional[SegRequest]] = [None] * max_batch
         self._slot_admit_s = np.zeros(max_batch, np.float64)
         self._slot_admit_tick = np.zeros(max_batch, np.int64)
@@ -458,8 +458,8 @@ class SegmentationEngine:
             )
             if size == self.tick_iters:
                 self._exe = exe
-        self._hoods, self._model, self._state, self._vote_plan = (
-            self.session.ticked_pool(self.bucket, batch=self.max_batch)
+        self._hoods, self._model, self._state = self.session.ticked_pool(
+            self.bucket, batch=self.max_batch
         )
 
     def _admit(self) -> int:
@@ -472,10 +472,10 @@ class SegmentationEngine:
             if not self._heap or self.slot_req[slot] is not None:
                 continue
             req = heapq.heappop(self._heap)[-1]
-            # Memoized admission (§17): the lane's initial TickState and
-            # vote plan are pure functions of the plan's padded inputs,
-            # so repeat traffic pays zero host-side argsort/init work.
-            h1, m1, lane, vplan = self.session.lane_state(
+            # Memoized admission (§17): the lane's initial TickState is a
+            # pure function of the plan's padded inputs, so repeat traffic
+            # pays zero host-side init work.
+            h1, m1, lane = self.session.lane_state(
                 req.plan, bucket=self.bucket, seed=req.seed
             )
             hold = False
@@ -500,12 +500,8 @@ class SegmentationEngine:
                         lab0c, mu0c, sig0c, self.bucket.n_hoods
                     )
                 hold = chaos_mod.hold_lane(req.rid)
-            self._hoods, self._model, self._state, self._vote_plan = (
-                _write_pools(
-                    (self._hoods, self._model, self._state, self._vote_plan),
-                    (h1, m1, lane, vplan),
-                    slot,
-                )
+            self._hoods, self._model, self._state = _write_pools(
+                (self._hoods, self._model, self._state), (h1, m1, lane), slot
             )
             self.slot_req[slot] = req
             self._slot_admit_s[slot] = now
@@ -728,7 +724,7 @@ class SegmentationEngine:
 
     def _try_tick(self):
         chaos_mod.on_execute(self._exe.key.backend)
-        return self._exe(self._hoods, self._model, self._state, self._vote_plan)
+        return self._exe(self._hoods, self._model, self._state)
 
     def _advance_pool(self):
         """One ticked-executable call under the session's fallback policy

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import dpp
-from repro.core.pmrf.hoods import Hoods
+from repro.core.pmrf.hoods import Hoods, vote_order
 
 
 def natural_hoods(graph, cliques) -> Hoods:
@@ -32,6 +32,7 @@ def natural_hoods(graph, cliques) -> Hoods:
         n_regions=n,
         neighbor_capacity=neighbor_capacity,
     )
+    perm, bounds = vote_order(np.asarray(vertex), n)
     return Hoods(
         vertex=vertex,
         hood_id=hood_id,
@@ -45,6 +46,8 @@ def natural_hoods(graph, cliques) -> Hoods:
         rep_test_label=rep[1],
         rep_hood_id=rep[2],
         rep_valid=rep[3],
+        vote_perm=jnp.asarray(perm),
+        vote_bounds=jnp.asarray(bounds),
     )
 
 

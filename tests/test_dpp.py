@@ -213,3 +213,104 @@ def test_map_applies_function():
     x = jnp.asarray([1.0, 2.0])
     y = jnp.asarray([3.0, 4.0])
     np.testing.assert_allclose(np.asarray(dpp.map_(lambda a, b: a * b, x, y)), [3.0, 8.0])
+
+
+def _segscan_loop(values, starts, op, reverse):
+    """Lane-by-lane segmented scan in the values' own dtype (lane 0 always
+    starts a run; backwards, a run starts at its last lane)."""
+    v = np.asarray(values)
+    s = np.asarray(starts, bool).copy()
+    s[0] = True
+    n = v.shape[0]
+    out = np.empty_like(v)
+    acc = None
+    for i in (range(n - 1, -1, -1) if reverse else range(n)):
+        first = (i == n - 1 or s[i + 1]) if reverse else s[i]
+        if first:
+            acc = v[i]
+        elif op == "add":
+            acc = acc + v[i]
+        out[i] = acc
+    return out
+
+
+# (lanes, run starts): runs across the 1024-lane rows, a run longer than a
+# row, a run per lane, a trailing run of padding lanes past a row's end,
+# and a length below one row.
+_SEGSCAN_LAYOUTS = {
+    "straddles-rows": (3000, [0, 1000, 1030, 2047, 2049]),
+    "run-longer-than-a-row": (2600, [0, 5]),
+    "unit-runs": (1500, list(range(1500))),
+    "padding-lanes": (1100, [0, 17, 600, 1050]),
+    "below-one-row": (7, [0, 3, 4]),
+}
+
+
+def _segscan_case(layout, rng, values):
+    n, starts = _SEGSCAN_LAYOUTS[layout]
+    flags = np.zeros(n, bool)
+    flags[starts] = True
+    return values(rng, n), flags
+
+
+@pytest.mark.parametrize("reverse", [False, True])
+@pytest.mark.parametrize("op", ["add", "copy"])
+@pytest.mark.parametrize("layout", sorted(_SEGSCAN_LAYOUTS))
+def test_segmented_scan_bitwise_on_integer_valued_floats(layout, op, reverse):
+    rng = np.random.default_rng(7)
+    vals, flags = _segscan_case(
+        layout, rng, lambda r, n: r.integers(0, 10, n).astype(np.float32))
+    got = dpp.segmented_scan(jnp.asarray(vals), jnp.asarray(flags), op,
+                             reverse=reverse)
+    assert got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got),
+                                  _segscan_loop(vals, flags, op, reverse))
+
+
+@pytest.mark.parametrize("reverse", [False, True])
+@pytest.mark.parametrize("layout", sorted(_SEGSCAN_LAYOUTS))
+def test_segmented_scan_float_sums_within_1e6(layout, reverse):
+    rng = np.random.default_rng(11)
+    vals, flags = _segscan_case(
+        layout, rng, lambda r, n: r.uniform(0, 100, n).astype(np.float32))
+    got = dpp.segmented_scan(jnp.asarray(vals), jnp.asarray(flags), "add",
+                             reverse=reverse)
+    want = _segscan_loop(vals.astype(np.float64), flags, "add", reverse)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
+
+
+def test_segmented_scan_reads_run_totals_with_empty_runs():
+    # Hood-style runs from offsets: empty runs repeat an offset and start
+    # nothing; padding lanes past the last offset form a run of their own.
+    sizes = np.array([3, 0, 1030, 0, 0, 5, 1, 0])
+    offsets = np.concatenate([[0], np.cumsum(sizes)])
+    n = int(offsets[-1]) + 900
+    flags = np.zeros(n, bool)
+    flags[offsets[:-1][sizes > 0]] = True
+    flags[offsets[-1]] = True
+    rng = np.random.default_rng(3)
+    vals = rng.integers(0, 5, n).astype(np.float32)
+    fwd = np.asarray(dpp.segmented_scan(jnp.asarray(vals), jnp.asarray(flags)))
+    totals = np.where(sizes > 0, fwd[np.maximum(offsets[1:] - 1, 0)], 0)
+    want = [vals[a:b].sum() for a, b in zip(offsets[:-1], offsets[1:])]
+    np.testing.assert_array_equal(totals, want)
+    back = np.asarray(dpp.segmented_scan(jnp.asarray(fwd), jnp.asarray(flags),
+                                         "copy", reverse=True))
+    for a, b in zip(offsets[:-1], offsets[1:]):
+        np.testing.assert_array_equal(back[a:b], vals[a:b].sum())
+
+
+def test_segmented_scan_batched_rows_match_unbatched_bitwise():
+    rng = np.random.default_rng(5)
+    vals = jnp.asarray(rng.uniform(0, 100, (3, 2500)).astype(np.float32))
+    flags = jnp.asarray(rng.random((3, 2500)) < 0.002)
+    batched = jax.vmap(lambda v, f: dpp.segmented_scan(v, f))(vals, flags)
+    for row in range(3):
+        np.testing.assert_array_equal(
+            np.asarray(batched[row]),
+            np.asarray(dpp.segmented_scan(vals[row], flags[row])))
+
+
+def test_segmented_scan_rejects_unknown_op():
+    with pytest.raises(ValueError, match="segmented_scan op"):
+        dpp.segmented_scan(jnp.zeros(4), jnp.zeros(4, bool), "max")

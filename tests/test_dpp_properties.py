@@ -328,3 +328,49 @@ def test_ticked_pool_parity_under_random_admission(order, seeds):
 def test_ticked_pool_parity_pinned():
     """Example-based companion that runs without hypothesis."""
     _assert_pool_matches_serial([2, 0, 1], [1, 0, 2])
+
+
+# ---------------------------------------------------------------------------
+# segmented_scan: a lane-by-lane oracle on integer-valued floats
+# ---------------------------------------------------------------------------
+
+
+def _segscan_oracle(vals, starts, op, reverse):
+    out = np.empty_like(vals)
+    n = vals.shape[0]
+    acc = None
+    for i in (range(n - 1, -1, -1) if reverse else range(n)):
+        first = i == (n - 1 if reverse else 0) or starts[i + 1 if reverse else i]
+        acc = vals[i] if first else (acc + vals[i] if op == "add" else acc)
+        out[i] = acc
+    return out
+
+
+def _check_segmented_scan(n, seed, density, op, reverse):
+    rng = np.random.default_rng(seed)
+    vals = rng.integers(0, 8, n).astype(np.float32)
+    starts = rng.random(n) < density
+    got = dpp.segmented_scan(jnp.asarray(vals), jnp.asarray(starts), op,
+                             reverse=reverse)
+    np.testing.assert_array_equal(
+        np.asarray(got), _segscan_oracle(vals, starts, op, reverse))
+
+
+# A few lengths, so each draw reuses a compiled program: one row, exactly
+# one row, a row and a lane, several rows.
+@settings(max_examples=30, deadline=None)
+@given(
+    st.sampled_from([5, 1024, 1025, 2900]),
+    st.integers(0, 2**31 - 1),
+    st.sampled_from([0.0, 0.001, 0.05, 0.5, 1.0]),
+    st.sampled_from(["add", "copy"]),
+    st.booleans(),
+)
+def test_segmented_scan_matches_lane_oracle(n, seed, density, op, reverse):
+    _check_segmented_scan(n, seed, density, op, reverse)
+
+
+@pytest.mark.parametrize("op,reverse", [("add", False), ("copy", True)])
+def test_segmented_scan_matches_lane_oracle_pinned(op, reverse):
+    """Example-based companion that runs without hypothesis."""
+    _check_segmented_scan(2900, 1, 0.002, op, reverse)

@@ -8,12 +8,14 @@ exactly once.
 """
 
 import os
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _scatter_map_step import scatter_map_step
 from repro import api
 from repro.core import dpp, synthetic
 from repro.core.pmrf import EMConfig, initialize, run_em
@@ -235,16 +237,20 @@ def test_fused_path_issues_fewer_launches_per_iteration():
     # Keyed-reduction launches only: plain `scatter` eqns are .at[].set
     # slice/pad writes that XLA fuses away, so they don't count as launches.
     reduce_prims = {"scatter-add", "scatter-min", "scatter-max"}
-    n_static = _count_prims(step("static", "xla", None), reduce_prims)
     ctx = energy_mod.make_static_context(hoods, model, backend="pallas-interpret")
+    # The unfused iteration as keyed scatters: 1 K-folded segment-sum
+    # (per-(hood,label) counts) + 1 (hood sizes) + 1 (hood energy) + 1
+    # K-folded vote scatter-add.  The static route reduces over the plan's
+    # runs instead, and the fused route runs everything keyed inside
+    # pallas_call.
+    with mock.patch.object(energy_mod, "map_step_runs", scatter_map_step):
+        n_scatter = _count_prims(step("static", "xla", ctx), reduce_prims)
+    n_static = _count_prims(step("static", "xla", ctx), reduce_prims)
     fused_jaxpr = step("static-pallas", "pallas-interpret", ctx)
     n_fused = _count_prims(fused_jaxpr, reduce_prims)
-    # static mode: 1 K-folded segment-sum (per-(hood,label) counts) + 1
-    # (hood sizes) + 1 (hood energy) + 1 K-folded vote scatter-add; fused
-    # mode: everything keyed runs inside pallas_call.
-    assert n_static >= 4
-    assert n_fused < n_static
-    assert n_fused == 0
+    assert n_scatter >= 4
+    assert n_fused < n_scatter
+    assert n_fused == n_static == 0
     # ... and the fused path really is kernel launches, not hidden scatters.
     # The fused EM tick (DESIGN.md §16) folds the label-count pass into the
     # launch itself, so a whole MAP iteration is exactly ONE pallas_call

@@ -200,13 +200,15 @@ def test_run_em_ticked_fused_route_is_one_launch_per_tick():
         )
     )
     bucket = sess.bucket_of(prob.hoods)
-    hoods, model, state, vplan = sess.ticked_pool(bucket, batch=2)
+    hoods, model, state = sess.ticked_pool(bucket, batch=2)
     emc = sess.config.em_config()
-    traced = em_mod.run_em_ticked.trace(hoods, model, state, vplan, emc, 2)
-    # tick_iters=2 unrolls two micro-steps: exactly one launch each, and
-    # nothing else in the ticked program launches a kernel at all.
+    traced = em_mod.run_em_ticked.trace(hoods, model, state, emc, 2)
+    # The tick's loop body holds exactly one launch, the fused tick, and
+    # nothing else in the ticked program launches a kernel at all: the
+    # hoisted hood sizes are run sums, not a segment-reduce launch.
     calls = _prim_paths(traced.jaxpr.jaxpr, {"pallas_call"})
-    assert len(calls) == 2, [p for p, _ in calls]
+    assert len(calls) == 1, [p for p, _ in calls]
+    assert "while" in calls[0][0]
 
 
 # ---------------------------------------------------------------------------

@@ -298,20 +298,17 @@ def test_ticked_pool_matches_golden_oracle(n_labels):
     )
     bucket = plan.bucket
     exe = sess.compile_ticked(bucket, batch=2, tick_iters=4)
-    hoods, model, state, vplan = sess.ticked_pool(bucket, batch=2)
+    hoods, model, state = sess.ticked_pool(bucket, batch=2)
     h1, m1, l0, mu0, sg0 = sess.lane_inputs(plan, bucket=bucket, seed=0)
     lane = em_mod.init_tick_lane(l0, mu0, sg0, bucket.n_hoods)
-    vp = em_mod.make_vote_plan(h1.vertex, bucket.n_regions)
     write = jax.jit(
         lambda pools, lanes, slot: jax.tree.map(
             lambda p, o: p.at[slot].set(o), pools, lanes
         )
     )
-    hoods, model, state, vplan = write(
-        (hoods, model, state, vplan), (h1, m1, lane, vp), 0
-    )
+    hoods, model, state = write((hoods, model, state), (h1, m1, lane), 0)
     for _ in range(200):
-        state, _steps = exe(hoods, model, state, vplan)
+        state, _steps = exe(hoods, model, state)
         if bool(np.asarray(state.done)[0]):
             break
     else:

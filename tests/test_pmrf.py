@@ -208,6 +208,32 @@ def test_hoods_structure():
     assert rep_lab.sum() == valid.sum()
 
 
+@pytest.mark.parametrize("n_regions", [5, 70000])
+def test_vote_order_is_a_stable_vertex_sort(n_regions):
+    # 70000 regions need the radix sort's second 16-bit digit.
+    rng = np.random.default_rng(n_regions)
+    vertex = rng.integers(0, n_regions + 1, 5000).astype(np.int32)
+    perm, bounds = hoods_mod.vote_order(vertex, n_regions)
+    np.testing.assert_array_equal(perm, np.argsort(vertex, kind="stable"))
+    np.testing.assert_array_equal(
+        bounds, np.searchsorted(vertex[perm], np.arange(n_regions + 2)))
+    assert perm.dtype == bounds.dtype == np.int32
+
+
+def test_vote_order_survives_padding_to_a_bucket():
+    img, _ = _tiny_problem()
+    lab = oversegment.slic(jnp.asarray(img), grid=(6, 6), iters=3)
+    g = build_region_graph(img, lab, 36)
+    hoods = build_hoods(g, enumerate_maximal_cliques(g))
+    padded = hoods_mod.pad_hoods(
+        hoods, capacity=hoods.capacity + 700, n_hoods=hoods.n_hoods + 9,
+        n_regions=hoods.n_regions + 5)
+    for h in (hoods, padded):
+        perm, bounds = hoods_mod.vote_order(np.asarray(h.vertex), h.n_regions)
+        np.testing.assert_array_equal(np.asarray(h.vote_perm), perm)
+        np.testing.assert_array_equal(np.asarray(h.vote_bounds), bounds)
+
+
 def _voronoi_graph(seed, n_regions=60, shape=(40, 40)):
     """Region graph of a random Voronoi partition: a random planar graph."""
     rng = np.random.default_rng(seed)
@@ -277,7 +303,8 @@ def test_build_hoods_matches_natural_oracle(case, request):
     assert (got.n_hoods, got.n_regions, got.n_elements) == (
         want.n_hoods, want.n_regions, want.n_elements)
     for field in ("vertex", "hood_id", "valid", "sizes", "offsets", "rep_old_index",
-                  "rep_test_label", "rep_hood_id", "rep_valid"):
+                  "rep_test_label", "rep_hood_id", "rep_valid", "vote_perm",
+                  "vote_bounds"):
         a, b = getattr(got, field), getattr(want, field)
         assert a.dtype == b.dtype and a.shape == b.shape, field
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=field)

@@ -108,13 +108,12 @@ def test_run_em_ticked_driver_matches_run_em_directly():
 
     batched = lambda t: jax.tree.map(lambda x: x[None], t)  # noqa: E731
     hoods_b, model_b = batched(h), batched(m)
-    vplan_b = batched(em_mod.make_vote_plan(h.vertex, h.n_regions))
     state = batched(em_mod.init_tick_lane(l0, mu0, s0, h.n_hoods))
     ticks = 0
     total_steps = 0
     while not bool(np.asarray(state.done)[0]):
         state, steps = em_mod.run_em_ticked(
-            hoods_b, model_b, state, vplan_b, cfg, 7
+            hoods_b, model_b, state, cfg, 7
         )
         assert 1 <= int(steps) <= 7
         total_steps += int(steps)

@@ -132,5 +132,5 @@ def test_static_em_compiles_for_v5e_at_the_full_slice_bucket(one_chip):
         mode=cfg["mode"], max_em_iters=cfg["max_em_iters"],
         max_map_iters=cfg["max_map_iters"]).em_config(backend="pallas-tpu")
     compiled = em_mod.run_em.lower(*inputs, em_config).compile()
-    # About 1.4 GB of temporaries at a full slice, of the chip's 16 GB.
+    # About 0.1 GB of temporaries at a full slice, of the chip's 16 GB.
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
