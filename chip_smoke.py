@@ -48,7 +48,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
 
 SIZE = 512                # the paper's synthetic slice edge
 VOLUME_SLICES = 4
-VOLUME_GRID = (64, 64)    # one region per 8x8 tile, as benchmarks/bench_sharded
+VOLUME_GRID = (64, 64)    # one region per 8x8 tile
 FUSED_GRID = (16, 16)     # a bucket inside the fused kernel's one-hot guard
 SERVE_REQUESTS = 8
 SERVE_SLOTS = 4

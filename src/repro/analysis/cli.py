@@ -1,7 +1,7 @@
 """``python -m repro.analysis`` — audit every executable the session
 layer can produce (DESIGN.md §15).
 
-Four passes, one deterministic report:
+Three passes, one deterministic report:
 
 1. **jaxpr audit** — traces every registered driver at the audit
    bucket for every (mode, backend, K) combo and runs the JX detectors
@@ -13,11 +13,6 @@ Four passes, one deterministic report:
 3. **budget sentinel** — compiles one tiny end-to-end scenario and
    measures the declared phase budgets (``budget.BUDGETS``) live;
    overshoot becomes a ``BG001`` finding.
-4. **calibration audit** — the checked-in plan-cost calibration table
-   (``src/repro/planning/calibration.json``, DESIGN.md §18) must load,
-   reproduce byte-for-byte from its own stored observations, carry
-   finite non-negative coefficients for every audited mode, and predict
-   monotonically along the capacity/K/width probe ladders (``CT00x``).
 
 Exit status: 0 when every finding is suppressed (and, under
 ``--check``, the checked-in ``ANALYSIS.json`` baseline matches);
@@ -222,107 +217,8 @@ def _audit_budgets(log) -> Tuple[List[Finding], Dict]:
     return findings, {"declared": declared, "measured": measured}
 
 
-def _audit_calibration(log) -> Tuple[List[Finding], Dict]:
-    """CT pass (DESIGN.md §18): the checked-in calibration table must be
-    readable, reproducible from its own stored observations, and yield
-    monotone predictions along the probe ladders."""
-    import math
-
-    from repro.planning import costmodel as planning
-
-    log("  calibration table audit")
-    findings: List[Finding] = []
-    entry: Dict = {"path": "src/repro/planning/calibration.json"}
-    try:
-        table = planning.load_table()
-    except (OSError, ValueError, KeyError) as exc:
-        findings.append(
-            Finding(
-                "CT001", "error", "calibration:table",
-                f"unreadable calibration table: {exc}",
-            )
-        )
-        return findings, entry
-    entry.update(
-        {
-            "platform": table.get("meta", {}).get("platform"),
-            "observations": len(table.get("observations", [])),
-            "modes": sorted(table.get("coefficients", {})),
-            "serial_frac": table.get("width", {}).get("serial_frac"),
-            "iter_cv": table.get("priors", {}).get("iter_cv"),
-        }
-    )
-
-    refit = planning.fit_table(table["observations"], table["meta"])
-    if planning.table_to_json(refit) != planning.default_table_path().read_text():
-        findings.append(
-            Finding(
-                "CT002", "error", "calibration:table",
-                "stored coefficients do not reproduce from the stored "
-                "observations (stale fit or hand edit); regenerate with "
-                "python -m repro.planning.calibrate --refit",
-            )
-        )
-
-    for mode, coeffs in sorted(table.get("coefficients", {}).items()):
-        for name, v in sorted(coeffs.items()):
-            if not (isinstance(v, (int, float)) and math.isfinite(v) and v >= 0):
-                findings.append(
-                    Finding(
-                        "CT003", "error", f"calibration:{mode}/{name}",
-                        f"coefficient {v!r} is not a finite non-negative number",
-                    )
-                )
-    for mode in registry.MODES:
-        if mode not in table.get("coefficients", {}):
-            findings.append(
-                Finding(
-                    "CT004", "warning", f"calibration:{mode}",
-                    "mode missing from the calibration grid; its predictions "
-                    "borrow another mode's coefficients",
-                )
-            )
-
-    model = planning.CostModel(table)
-    probe = registry.CALIBRATION_PROBE_BUCKETS
-    for mode in registry.MODES:
-        caps = [model.predict_solve(mode=mode, bucket=b) for b in probe]
-        if any(b < a for a, b in zip(caps, caps[1:])):
-            findings.append(
-                Finding(
-                    "CT005", "error", f"calibration:{mode}/capacity",
-                    "predicted solve seconds not monotone over the bucket "
-                    f"ladder {probe}",
-                )
-            )
-        ks = [
-            model.predict_solve(mode=mode, bucket=probe[1], n_labels=k)
-            for k in registry.KS
-        ]
-        if any(b < a for a, b in zip(ks, ks[1:])):
-            findings.append(
-                Finding(
-                    "CT005", "error", f"calibration:{mode}/K",
-                    f"predicted solve seconds not monotone over K={registry.KS}",
-                )
-            )
-        ws = [
-            model.predict_batched(mode=mode, bucket=probe[1], width=w)
-            for w in registry.CALIBRATION_PROBE_WIDTHS
-        ]
-        if any(b < a for a, b in zip(ws, ws[1:])):
-            findings.append(
-                Finding(
-                    "CT005", "error", f"calibration:{mode}/width",
-                    "predicted lockstep seconds not monotone over widths "
-                    f"{registry.CALIBRATION_PROBE_WIDTHS}",
-                )
-            )
-    return findings, entry
-
-
 def run_audit(verbose: bool = True) -> Dict:
-    """Run all four passes; returns the (deterministic) report dict."""
+    """Run all three passes; returns the (deterministic) report dict."""
     log = (lambda s: print(s, file=sys.stderr)) if verbose else (lambda s: None)
 
     log("jaxpr audit:")
@@ -331,10 +227,8 @@ def run_audit(verbose: bool = True) -> Dict:
     pl_findings, pl_entries = _audit_kernels(log)
     budget_mod.reset_all()  # the audit's own traces don't count
     bg_findings, budgets = _audit_budgets(log)
-    log("calibration audit:")
-    ct_findings, calibration = _audit_calibration(log)
 
-    all_findings = sorted(jx_findings + pl_findings + bg_findings + ct_findings)
+    all_findings = sorted(jx_findings + pl_findings + bg_findings)
     all_findings, stale = apply_suppressions(all_findings, registry.SUPPRESSIONS)
     unsuppressed = [f for f in all_findings if not f.suppressed]
 
@@ -351,7 +245,6 @@ def run_audit(verbose: bool = True) -> Dict:
         "jaxpr": jx_entries,
         "kernels": pl_entries,
         "budgets": budgets,
-        "calibration": calibration,
         "suppressions": [
             {"code": s.code, "site_pattern": s.site_pattern, "reason": s.reason}
             for s in registry.SUPPRESSIONS

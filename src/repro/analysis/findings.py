@@ -26,16 +26,6 @@ Detector codes (the taxonomy; one class per failure mode):
             declared dimension semantics (safe only by Mosaic's implicit
             sequential default — declare it)
 ``BG001``   a measured phase exceeded its declared retrace/compile budget
-``CT001``   checked-in calibration table missing or unreadable
-``CT002``   stored calibration coefficients do not reproduce from the
-            stored observations (stale fit or hand edit — the table is a
-            pure function of its own observations, DESIGN.md §18)
-``CT003``   calibration coefficient is not a finite non-negative number
-``CT004``   an audited mode is absent from the calibration grid (its
-            predictions borrow another mode's coefficients)
-``CT005``   cost-model prediction non-monotone along a probe ladder
-            (capacity / K / width) — the autotuner's rankings are
-            untrustworthy
 ==========  ============================================================
 
 Severity is ``error`` for defects that corrupt results (races, bounds,

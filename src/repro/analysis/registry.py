@@ -36,8 +36,6 @@ __all__ = [
     "loop_budget",
     "SUPPRESSIONS",
     "KERNEL_NAMES",
-    "CALIBRATION_PROBE_BUCKETS",
-    "CALIBRATION_PROBE_WIDTHS",
 ]
 
 #: Production-representative bucket (capacity, n_hoods, n_regions) the
@@ -67,20 +65,6 @@ DRIVERS: Tuple[DriverSpec, ...] = (
     DriverSpec("run_em_batched", batched=True, ticked=False),
     DriverSpec("run_em_ticked", batched=True, ticked=True),
 )
-
-#: Calibration-table audit probes (CT codes, DESIGN.md §18): the cost
-#: model's predictions must be monotone non-decreasing along each of
-#: these ladders — capacity (the bucket ladder, each dim scaling
-#: together the way the oversegmentation policy scales them), label
-#: count K, and lockstep width.  Non-monotone predictions mean a fit
-#: went numerically wrong and the autotuner's rankings are garbage.
-CALIBRATION_PROBE_BUCKETS: Tuple[Tuple[int, int, int], ...] = (
-    (4096, 256, 192),
-    (8192, 512, 384),
-    (16384, 1024, 768),
-    (65536, 4096, 4096),
-)
-CALIBRATION_PROBE_WIDTHS: Tuple[int, ...] = (1, 2, 4, 8)
 
 #: Pallas kernels registered in kernels/ops.py that the checker audits.
 KERNEL_NAMES: Tuple[str, ...] = (

@@ -43,7 +43,6 @@ from repro import obs
 from repro.analysis import budget as budget_mod
 from repro.api.config import ExecutionConfig
 from repro.api.errors import FallbackError, PlanError
-from repro.planning import costmodel as planning_mod
 from repro.core.pmrf import distributed as distributed_mod
 from repro.core.pmrf import em as em_mod
 from repro.core.pmrf import energy as energy_mod
@@ -104,16 +103,11 @@ class ExecutableKey(NamedTuple):
 class Plan:
     """A planned (initialized + bucketed) segmentation problem.
 
-    ``init_seconds`` is the ``plan`` span less its ``plan.cost`` child: the
-    plan's own work, without the cost model's prediction."""
+    ``init_seconds`` is the whole ``plan`` span."""
 
     problem: pipeline_mod.Problem
     bucket: BucketKey
     init_seconds: float
-    # Cost-model estimate (DESIGN.md §18) for one warm execute of this
-    # plan under the session's config — what the autotuner compares when
-    # routing, surfaced here so callers can budget before executing.
-    predicted_optimize_s: Optional[float] = None
     # The request id this plan's spans carry (``repro.obs``).
     id: int = field(default_factory=lambda: next(_PLAN_IDS), compare=False)
     # Padded-input memo keyed by (bucket, seed, init): repeat executes of
@@ -159,6 +153,16 @@ class _Pending(NamedTuple):
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def _batch_pays(capacities: Sequence[int], platform: str) -> bool:
+    """``segment_stack(batch="auto")``'s rule: coalesce a stack into one
+    lockstep launch only on an accelerator (XLA:CPU runs vmapped lanes one
+    after another, so the lockstep padding is pure overhead there) and only
+    when every slice's capacity is within 2x of the smallest (one bucket,
+    bounded padding waste)."""
+    caps = list(capacities)
+    return len(caps) > 1 and max(caps) <= 2 * min(caps) and platform != "cpu"
 
 
 def _abstract_inputs(
@@ -301,50 +305,11 @@ class Segmenter:
                 segment_bucket=self.config.segment_bucket,
             )
             bucket = self.bucket_of(problem.hoods)
-            with obs.span("plan.cost") as cost:
-                predicted = self.cost_model().predict_solve(
-                    mode=self.config.mode,
-                    bucket=bucket,
-                    n_labels=self.config.n_labels,
-                    shards=self.config.shards,
-                    precision=self.config.precision,
-                    max_em_iters=self.config.max_em_iters,
-                    max_map_iters=self.config.max_map_iters,
-                )
         return Plan(
             problem=problem,
             bucket=bucket,
-            init_seconds=whole.seconds - cost.seconds,
-            predicted_optimize_s=predicted,
+            init_seconds=whole.seconds,
             id=plan_id,
-        )
-
-    def cost_model(self) -> planning_mod.CostModel:
-        """The calibrated plan cost model for this session's platform
-        (DESIGN.md §18) — every autotuned routing decision below queries
-        this one object."""
-        return planning_mod.model_for(self.config)
-
-    def choose_batch(
-        self, plans: Sequence[Plan], *, joint_bucket: Optional[BucketKey] = None
-    ) -> planning_mod.BatchDecision:
-        """Cost-model verdict for coalescing ``plans`` into one lockstep
-        launch vs executing them serially (what ``segment_stack``'s
-        ``batch="auto"`` routes on — exposed so callers and benchmarks can
-        inspect the predicted seconds behind the decision)."""
-        if joint_bucket is None:
-            joint_bucket = BucketKey(
-                *(max(b[d] for b in (p.bucket for p in plans)) for d in range(3))
-            )
-        c = self.config
-        return self.cost_model().choose_batch(
-            mode=c.mode,
-            buckets=[p.bucket for p in plans],
-            joint_bucket=joint_bucket,
-            n_labels=c.n_labels,
-            precision=c.precision,
-            max_em_iters=c.max_em_iters,
-            max_map_iters=c.max_map_iters,
         )
 
     # ------------------------------------------------------------------
@@ -897,19 +862,12 @@ class Segmenter:
     ) -> Tuple[List[pipeline_mod.SegmentationResult], float]:
         """Segment a slice stack; returns (results, mean optimize seconds).
 
-        ``batch="always"``/``"auto"`` submit every slice under the stack's
-        joint bucket (elementwise max) so the whole volume coalesces into
-        one launch; ``"never"`` always runs serially.  ``"auto"`` asks the
-        calibrated cost model (DESIGN.md §18) which side is predicted
-        faster: the batched side is priced at the joint bucket with the
-        measured lockstep-iteration inflation and the platform's
-        lane-serialization factor (on XLA:CPU the vmapped lanes execute
-        serially, so batching loses — the model predicts the BENCH_pmrf
-        inversion instead of hard-coding a platform check), the serial
-        side at each lane's own bucket (so a wide capacity spread shows up
-        as padding cost, not as a fixed 2x rule).  Setting
-        ``REPRO_DISABLE_AUTOTUNE=1`` restores the pre-§18 heuristic
-        (accelerator-only batching with a 2x capacity-spread cap).
+        ``batch="always"`` submits every slice under the stack's joint
+        bucket (elementwise max) so the whole volume coalesces into one
+        launch; ``"never"`` always runs serially.  ``"auto"`` batches by
+        :func:`_batch_pays` (DESIGN.md §18): only on an accelerator, only
+        unsharded, and only when every slice's capacity is within 2x of
+        the smallest.
         """
         if batch not in ("auto", "always", "never"):
             raise ValueError(f"batch must be auto/always/never, got {batch!r}")
@@ -927,22 +885,20 @@ class Segmenter:
             raise ValueError("segment_stack: empty image stack")
         plans = [self.plan(img) for img in images]
 
-        joint = BucketKey(
-            *(max(b[d] for b in (p.bucket for p in plans)) for d in range(3))
-        )
         if batch == "always":
             use_batch = True
-        elif batch == "never" or self.config.shards > 1 or len(plans) < 2:
+        elif batch == "never" or self.config.shards > 1:
             use_batch = False
-        elif planning_mod.autotune_disabled():
-            use_batch = planning_mod.legacy_batch_choice(
+        else:
+            use_batch = _batch_pays(
                 [p.problem.hoods.capacity for p in plans], jax.default_backend()
             )
-        else:
-            use_batch = self.choose_batch(plans, joint_bucket=joint).use_batch
         if not use_batch:
             results = [self.execute(p, seed=seed) for p in plans]
         else:
+            joint = BucketKey(
+                *(max(b[d] for b in (p.bucket for p in plans)) for d in range(3))
+            )
             for p in plans:
                 self.submit(p, seed=seed, bucket=joint)
             results = self.drain()

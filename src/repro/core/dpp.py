@@ -19,7 +19,7 @@ Two semantic adaptations vs. the VTK-m originals (see DESIGN.md §2):
 
 Inside :func:`profiled` every primitive runs eagerly to completion as a
 ``dpp.<Primitive>`` span (``repro.obs``), so the per-primitive breakdown of
-the paper's §4.3.2 can be reproduced (``benchmarks/bench_fig4.py``).
+the paper's §4.3.2 can be reproduced.
 """
 
 from __future__ import annotations

@@ -517,7 +517,7 @@ def run_em_batched(
 #
 # ``run_em_batched`` vmaps the *whole* while_loop, so a stack of problems
 # advances in lockstep until the slowest lane converges — every lane pays
-# the max iteration count (the BENCH_api.json 0.45x inversion).  The ticked
+# the max iteration count.  The ticked
 # driver flattens the nested EM/MAP while_loops into a single-micro-step
 # state machine (:class:`TickState` + :func:`_tick_micro`) and advances a
 # fixed pool of lanes by ``tick_iters`` masked micro-steps per call.

@@ -21,9 +21,8 @@ given the same schedule), so runtime ratios isolate the execution model —
 the paper's experimental design.  ``golden_em`` trades their float64
 comfort for exact float32 trajectory parity.
 
-On this container there is one core, so ``coarse_em`` measures the
-coarse-grained formulation at concurrency 1 (the paper's p=1 column);
-the DPP-vs-reference ratio at p=1 is reported in bench_fig3.
+On a single core ``coarse_em`` measures the coarse-grained formulation
+at concurrency 1 (the paper's p=1 column).
 """
 
 from __future__ import annotations

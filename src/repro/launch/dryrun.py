@@ -11,9 +11,8 @@ production meshes — 16x16 (single pod, 256 chips) and 2x16x16 (two pods,
     compiled.cost_analysis()     -> per-chip FLOPs / HBM bytes
     parse_collectives(hlo text)  -> per-chip collective bytes by op
 
-into one JSON artifact per cell under ``benchmarks/artifacts/dryrun/``.
-EXPERIMENTS.md §Dry-run / §Roofline and benchmarks/bench_roofline.py read
-these artifacts.
+into one JSON artifact per cell under ``artifacts/dryrun/`` at the repo
+root; ``tests/test_dryrun.py`` checks them once generated.
 
 The two module-level lines above MUST stay the first statements: JAX locks
 the device count at first backend init, and only the dry-run may see the
@@ -40,7 +39,7 @@ from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import Roofline, analytic_flash_traffic, model_flops_for
 from repro.launch.specs import build_step, runnable_cells
 
-ARTIFACT_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "artifacts" / "dryrun"
+ARTIFACT_DIR = Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
 
 
 def artifact_path(arch: str, shape: str, multi_pod: bool, variant: str = "") -> Path:

@@ -21,7 +21,6 @@ Usage::
     PYTHONPATH=src python -m repro.launch.segment --slices 2 --size 96 \
         --mode static --backend auto --repeat 3 --dataset synthetic
     PYTHONPATH=src python -m repro.launch.segment --shards 8 --mode static
-    PYTHONPATH=src python -m repro.launch.segment --shards auto  # cost model picks
     PYTHONPATH=src python -m repro.launch.segment --spans  # per-span table on stderr
 """
 
@@ -67,12 +66,9 @@ def main() -> None:
         "it pays (accelerators, bounded capacity spread)",
     )
     ap.add_argument(
-        "--shards", default="1",
+        "--shards", type=int, default=1,
         help="block-partition hood elements over an N-device mesh; on CPU "
-        "this forces N virtual host devices (usable anywhere).  'auto' "
-        "lets the calibrated cost model (DESIGN.md §18) pick the predicted-"
-        "fastest shard count for the problem size; an explicit N that the "
-        "model predicts slower than its own choice gets a one-line warning",
+        "this forces N virtual host devices (usable anywhere)",
     )
     ap.add_argument("--dataset", choices=("synthetic", "experimental"),
                     default="synthetic")
@@ -86,17 +82,13 @@ def main() -> None:
     )
     args = ap.parse_args()
 
-    auto_shards = args.shards == "auto"
-    forced_shards = None if auto_shards else int(args.shards)
     # The XLA device count is fixed at backend init, so virtual host
     # devices must be forced before the first jax import (repro.xla_env
-    # docstring) — for 'auto' that means the widest candidate the cost
-    # model may pick, BEFORE the choice is made.
-    max_auto_shards = 8
-    if auto_shards or forced_shards > 1:
+    # docstring).
+    if args.shards > 1:
         from repro.xla_env import force_host_device_count
 
-        force_host_device_count(max_auto_shards if auto_shards else forced_shards)
+        force_host_device_count(args.shards)
     from repro.xla_env import use_compile_cache
 
     use_compile_cache()
@@ -104,12 +96,12 @@ def main() -> None:
     from repro import obs
 
     with obs.recording() if args.spans else contextlib.nullcontext() as rec:
-        _run(ap, args, auto_shards, forced_shards)
+        _run(ap, args)
     if rec is not None:
         print(rec.format_table(), file=sys.stderr)
 
 
-def _run(ap, args, auto_shards, forced_shards) -> None:
+def _run(ap, args) -> None:
     from repro import api
     from repro.core import metrics as M
     from repro.core import synthetic as S
@@ -134,47 +126,14 @@ def _run(ap, args, auto_shards, forced_shards) -> None:
         )
     images = [np.asarray(im) for im in vol.images]
 
-    base_config = api.ExecutionConfig(
+    sess = api.Segmenter(api.ExecutionConfig(
         backend=args.backend,
         mode=args.mode,
         init=args.init,
         overseg_grid=(args.grid, args.grid),
         n_labels=args.labels,
-    )
-
-    # Shard-count routing (DESIGN.md §18): plan one slice with a probe
-    # session to learn the problem's bucket (bucketing is shard-
-    # independent), then ask the calibrated cost model which shard count
-    # is predicted fastest.  An explicit --shards N that the model
-    # predicts slower than its own choice gets a one-line warning.
-    import jax
-
-    from repro.planning import costmodel as planning
-
-    probe = api.Segmenter(base_config)
-    probe_plan = probe.plan(images[0])
-    candidates = sorted(
-        {1, forced_shards or 1}
-        | {s for s in (2, 4, 8) if s <= jax.device_count()}
-    )
-    decision = probe.cost_model().choose_shards(
-        mode=base_config.mode,
-        bucket=probe_plan.bucket,
-        candidates=candidates,
-        n_labels=base_config.n_labels,
-        max_em_iters=base_config.max_em_iters,
-        max_map_iters=base_config.max_map_iters,
-    )
-    if auto_shards:
-        shards = 1 if planning.autotune_disabled() else decision.shards
-        print(json.dumps({"shards_auto": decision.as_dict()}))
-    else:
-        shards = forced_shards
-        warning = decision.warn_if_forced(shards)
-        if warning is not None:
-            print(f"warning: {warning}", file=sys.stderr)
-
-    sess = api.Segmenter(base_config.with_(shards=shards))
+        shards=args.shards,
+    ))
 
     results = None
     for r in range(max(1, args.repeat)):

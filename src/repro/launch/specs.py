@@ -1,6 +1,6 @@
 """ShapeDtypeStruct input stand-ins + step builders for every
 (architecture x shape) cell — shared by the dry-run, the drivers, and the
-roofline benchmarks.
+roofline model (``launch/roofline.py``).
 
 ``input_specs(cfg, shape)`` returns allocation-free stand-ins for every
 model input of the cell's step kind:

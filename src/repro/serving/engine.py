@@ -13,8 +13,7 @@ are fixed at compile time, admission and retirement are pure data writes.
 This is the slot-based continuous-batching scheduling model of production
 LM servers (``repro.serving.lm``) applied to PMRF optimization: the
 lockstep alternative (``run_em_batched``) runs every lane to the *slowest*
-lane's convergence (the BENCH_api.json ``batched_speedup_x: 0.45``
-inversion), while this engine keeps every slot busy with useful work —
+lane's convergence, while this engine keeps every slot busy with useful work —
 a lane only ever pays its own iterations (plus at most one tick of
 granularity waste, and not even that when the whole pool converges: the
 ticked driver exits at the convergence boundary, DESIGN.md §17).
@@ -48,7 +47,7 @@ a :class:`SegCompletion` with an error ``status``; a lane that simply
 never converges is evicted after a fixed micro-step residency budget.
 Healthy co-resident lanes are bitwise unaffected (lanes are isolated in
 every keyed reduction — chaos-tested).  Tick times feed a
-:class:`~repro.training.fault.StragglerWatchdog`; execute failures retry
+:class:`~repro.serving.watchdog.StragglerWatchdog`; execute failures retry
 through the session's :class:`~repro.api.config.FallbackPolicy`.
 
 Mixed-K traffic (DESIGN.md §13): the pool is compiled at the session's
@@ -76,10 +75,9 @@ from repro.api.errors import FallbackError, RequestError
 from repro.api.session import BucketKey, Plan, Segmenter
 from repro.core.pmrf import em as em_mod
 from repro.core.pmrf import pipeline as pipeline_mod
-from repro.planning import costmodel as planning_mod
-from repro.planning.lsq import DecayedAffineFit
+from repro.serving.lsq import DecayedAffineFit
+from repro.serving.watchdog import StragglerWatchdog
 from repro.testing import chaos as chaos_mod
-from repro.training.fault import StragglerWatchdog
 
 _INF = math.inf
 
@@ -94,10 +92,10 @@ DEFAULT_TICK_LADDER = (1, 2, 4, 8, 16)
 
 # ---------------------------------------------------------------------------
 # Pool surgery ops — module level so their jit caches are shared by every
-# engine instance (keyed on pool shapes).  When these lived as per-engine
-# ``jax.jit(lambda ...)`` closures, every fresh engine — including each
-# fault-sweep engine in bench_serve — paid ~0.5s recompiling identical
-# programs mid-serving (DESIGN.md §17's regression post-mortem).
+# engine instance (keyed on pool shapes).  As per-engine
+# ``jax.jit(lambda ...)`` closures, every fresh engine paid ~0.5s
+# recompiling identical programs mid-serving (DESIGN.md §17's regression
+# post-mortem).
 # ---------------------------------------------------------------------------
 
 
@@ -325,13 +323,9 @@ class SegmentationEngine:
         self._phase_s = {"admit": 0.0, "advance": 0.0, "sync": 0.0, "retire": 0.0}
         self._size_ticks: Dict[int, int] = {}
         self._size_s: Dict[int, float] = {}
-        # One cost-model implementation, two consumers (DESIGN.md §18):
-        # the online tick-cost fit is the same DecayedAffineFit the
-        # calibration machinery uses, and until it has observations it
-        # falls back to the calibrated table's tick-cost prior instead of
-        # blind constants (see _tick_cost_prior).
+        # Until it has observations the fit returns its cold-start prior,
+        # a = b = 5 ms.
         self._cm = DecayedAffineFit(decay=0.95)
-        self._tick_prior: Optional[Tuple[float, float]] = None
         self._steps_ewma: Optional[float] = None   # micro-steps per request
         self._desired_streak: Tuple[int, int] = (tick_iters, 0)
 
@@ -598,27 +592,6 @@ class SegmentationEngine:
         self._size_s[size] = self._size_s.get(size, 0.0) + duration
         self._cm.observe(steps, duration)
 
-    def _tick_cost_default(self) -> Tuple[float, float]:
-        """Cold-start ``(a, b)`` for the tick-cost fit: the calibrated
-        plan model's prediction for this pool (DESIGN.md §18) — per-launch
-        dispatch as ``a``, one pool micro-step as ``b`` — so the first
-        adaptive decisions start from measured-platform numbers instead of
-        blind constants.  Falls back to the historical ``(5e-3, 5e-3)``
-        when the bucket is still unknown or autotuning is disabled."""
-        if self._tick_prior is not None:
-            return self._tick_prior
-        if self.bucket is None or planning_mod.autotune_disabled():
-            return 5e-3, 5e-3
-        cfg = self.session.config
-        self._tick_prior = planning_mod.model_for(cfg).tick_cost_prior(
-            mode=cfg.mode,
-            bucket=self.bucket,
-            width=self.max_batch,
-            n_labels=cfg.n_labels,
-            precision=cfg.precision,
-        )
-        return self._tick_prior
-
     def cost_model(self) -> Tuple[float, float]:
         """Fitted per-tick cost ``(a, b)``: ``cost ~= a + b*steps`` seconds
         (fixed host+dispatch overhead vs marginal micro-step cost).
@@ -636,7 +609,7 @@ class SegmentationEngine:
             if self.ticks
             else 0.0
         )
-        return self._cm.fit(a_floor=a_floor, default=self._tick_cost_default())
+        return self._cm.fit(a_floor=a_floor)
 
     def _request_steps_estimate(self) -> float:
         if self._steps_ewma is not None:

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro import api
+from repro.api import session as session_mod
 from repro.core import synthetic
 from repro.core.pmrf import em as em_mod
 from repro.core.pmrf import pipeline
@@ -236,6 +237,42 @@ def test_drain_failure_requeues_unprocessed():
     # after dropping the poisoned request, the healthy one still drains
     seg._pending.pop(0)
     assert len(seg.drain()) == 1
+
+
+# ---------------------------------------------------------------------------
+# segment_stack(batch="auto"): the batching rule + warm reuse
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "capacities, platform, batched",
+    [
+        ([100, 120], "cpu", False),    # XLA:CPU runs vmapped lanes serially
+        ([100, 120], "tpu", True),     # accelerator, capacities within 2x
+        ([100, 300], "tpu", False),    # accelerator, spread over 2x
+        ([100], "tpu", False),         # one plan
+    ],
+)
+def test_escape_hatch_restores_legacy_heuristic(capacities, platform, batched):
+    assert session_mod._batch_pays(capacities, platform) is batched
+
+
+def test_autotuned_segment_stack_reuses_warm_executables():
+    seg = _fresh()
+    imgs = _images(n=3)
+    res_a, _ = seg.segment_stack(imgs, batch="auto")
+    misses = seg.stats.misses
+    before = dict(em_mod.TRACE_COUNTS)
+    res_b, _ = seg.segment_stack(imgs, batch="auto")
+    assert em_mod.TRACE_COUNTS == before, \
+        "a second segment_stack must reuse the warm executable cache, not retrace"
+    assert seg.stats.misses == misses
+    # on CPU the rule runs the stack serially: the same results as "never"
+    res_loop, _ = seg.segment_stack(imgs, batch="never")
+    assert seg.stats.misses == misses
+    for a, b, c in zip(res_a, res_b, res_loop):
+        assert (np.asarray(a.segmentation) == np.asarray(b.segmentation)).all()
+        assert (np.asarray(a.segmentation) == np.asarray(c.segmentation)).all()
 
 
 # ---------------------------------------------------------------------------

@@ -178,7 +178,7 @@ def test_artifacts_exist_and_complete():
     from repro.configs import ARCHS
     from repro.configs.base import SHAPES
 
-    art = Path(__file__).parent.parent / "benchmarks" / "artifacts" / "dryrun"
+    art = Path(__file__).parent.parent / "artifacts" / "dryrun"
     if not art.exists():
         pytest.skip("dry-run artifacts not generated yet")
     missing, bad = [], []

@@ -1,13 +1,15 @@
 """Serving-engine tests: ticked masked EM vs serial run_em.
 
 The acceptance bar of the serving PR (DESIGN.md §12): on a stack of
-problems with deliberately mixed convergence iteration counts — the exact
-case that produced BENCH_api.json's 0.45x lockstep inversion — every
+problems with deliberately mixed convergence iteration counts — the case
+in which lockstep batching runs every lane to the slowest one — every
 request served through the continuous-batching engine must reproduce the
 serial ``run_em`` result bit-for-bit in every label-visible output
 (labels, segmentation, mu, sigma, em/map iteration counts; energies to
 float-reduction tolerance), and admission/retirement across ticks must
-never retrace the compiled tick program.
+never retrace the compiled tick program.  The engine's host-side
+observers (the tick-cost fit and the straggler watchdog) are pinned at the
+end.
 """
 
 import numpy as np
@@ -19,8 +21,12 @@ from repro import api
 from repro.core import synthetic
 from repro.core.pmrf import em as em_mod
 from repro.serving import SegmentationEngine
+from repro.serving.lsq import DecayedAffineFit
+from repro.serving.watchdog import StragglerWatchdog
 
-pytestmark = pytest.mark.slow  # full-EM runs: in the tier-1 slow bucket
+# The engine tests are full-EM runs: in the tier-1 slow bucket.  The
+# host-side observer tests at the end run no EM and carry no mark.
+slow = pytest.mark.slow
 
 
 def _session(**overrides):
@@ -54,6 +60,7 @@ def _assert_matches_serial(completion, want):
     )
 
 
+@slow
 def test_ticked_engine_bit_identical_on_mixed_convergence():
     sess = _session()
     plans = _mixed_plans(sess)
@@ -73,6 +80,7 @@ def test_ticked_engine_bit_identical_on_mixed_convergence():
     assert engine.ticks > 0 and engine.stats()["occupancy"] > 0.5
 
 
+@slow
 def test_admission_and_retirement_never_retrace():
     sess = _session()
     plans = _mixed_plans(sess, n=5)
@@ -97,6 +105,7 @@ def test_admission_and_retirement_never_retrace():
     assert em_mod.TRACE_COUNTS == before
 
 
+@slow
 def test_run_em_ticked_driver_matches_run_em_directly():
     """Driver-level identity, no engine: tick the machine to completion on
     one lane and compare the full EMResult against run_em."""
@@ -134,6 +143,7 @@ def test_run_em_ticked_driver_matches_run_em_directly():
     )
 
 
+@slow
 def test_ticked_vmap_path_matches_serial_faithful_mode():
     """The non-static modes go through the vmapped lane step — same
     bit-identity contract."""
@@ -147,6 +157,7 @@ def test_ticked_vmap_path_matches_serial_faithful_mode():
     _assert_matches_serial(completion, want)
 
 
+@slow
 def test_deadline_ordered_admission():
     sess = _session()
     plans = _mixed_plans(sess, n=3)
@@ -165,6 +176,7 @@ def test_deadline_ordered_admission():
         assert c.ticks_resident >= 1
 
 
+@slow
 def test_admission_is_deterministic_with_all_none_deadlines():
     """Equal deadline keys (here: every deadline None) tie-break by rid —
     admission order is a pure function of the submitted rids, not of heap
@@ -183,6 +195,7 @@ def test_admission_is_deterministic_with_all_none_deadlines():
         engine.submit(plans[0], rid="abc")
 
 
+@slow
 def test_priority_classes_order_admission_before_deadlines():
     sess = _session()
     plans = _mixed_plans(sess, n=3)
@@ -194,6 +207,7 @@ def test_priority_classes_order_admission_before_deadlines():
     assert [c.rid for c in completions] == [2, 1, 0]
 
 
+@slow
 def test_adaptive_tick_cache_per_size_no_retrace_no_alias():
     """``ExecutableKey.tick_iters`` under adaptive ticking (DESIGN.md §17):
     pool bring-up traces each ladder size exactly once, tick-size switches
@@ -258,6 +272,7 @@ def test_adaptive_tick_cache_per_size_no_retrace_no_alias():
     _assert_matches_serial(c2, serial[0])
 
 
+@slow
 def test_mixed_k_requests_share_one_pool():
     """DESIGN.md §13: a K=3 pool serves K=2 and K=3 requests together.
     Smaller-K plans are label-padded with inert sentinel labels, so each
@@ -300,6 +315,7 @@ def test_mixed_k_requests_share_one_pool():
         engine2.submit(plan3)
 
 
+@slow
 def test_engine_rejects_oversized_and_sharded():
     sess = _session()
     plans = _mixed_plans(sess, n=1)
@@ -312,3 +328,52 @@ def test_engine_rejects_oversized_and_sharded():
         api.Segmenter(api.ExecutionConfig(shards=2)).compile_ticked(
             api.BucketKey(64, 8, 8), batch=2
         )
+
+
+# ---------------------------------------------------------------------------
+# the engine's host-side observers: DecayedAffineFit, StragglerWatchdog
+# ---------------------------------------------------------------------------
+
+
+def test_decayed_affine_fit_recovers_line():
+    f = DecayedAffineFit(decay=1.0)  # undecayed: plain least squares
+    for x in (1.0, 2.0, 3.0, 4.0, 5.0):
+        f.observe(x, 2.0 + 3.0 * x)
+    a, b = f.fit()
+    assert abs(a - 2.0) < 1e-9 and abs(b - 3.0) < 1e-9
+
+
+def test_decayed_affine_fit_fallback_ladder():
+    # no observations -> default (clamped by a_floor / b_min)
+    f = DecayedAffineFit()
+    assert f.fit(default=(0.01, 0.02)) == (0.01, 0.02)
+    assert f.fit(a_floor=0.5, default=(0.01, 0.02)) == (0.5, 0.02)
+    # one observation -> the engine's 30/70 mean split
+    f.observe(4.0, 1.0)
+    a, b = f.fit()
+    assert abs(a - 0.3) < 1e-12 and abs(b - 0.7 / 4.0) < 1e-12
+    # zero x-variance -> still the mean split, never a divide-by-zero
+    f.observe(4.0, 2.0)
+    a, b = f.fit()
+    assert a > 0 and b > 0
+
+
+def test_decayed_affine_fit_tracks_regime_change():
+    f = DecayedAffineFit(decay=0.95)
+    for x in (1.0, 2.0, 4.0, 8.0):
+        f.observe(x, 0.1 + 0.01 * x)
+    # cost regime doubles; the decayed fit must follow recent samples
+    for _ in range(40):
+        for x in (1.0, 2.0, 4.0, 8.0):
+            f.observe(x, 0.2 + 0.02 * x)
+    a, b = f.fit()
+    assert abs(a - 0.2) < 0.02 and abs(b - 0.02) < 0.005
+
+
+def test_straggler_watchdog_flags_slow_steps():
+    wd = StragglerWatchdog(alpha=0.5, threshold=2.0, warmup_steps=0)
+    flagged = []
+    for i, dt in enumerate([0.1, 0.1, 0.1, 0.5, 0.1]):
+        flagged.append(wd.observe(i, dt))
+    assert flagged == [False, False, False, True, False]
+    assert len(wd.events) == 1 and wd.events[0]["step"] == 3

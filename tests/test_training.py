@@ -14,7 +14,7 @@ from _hyp import given, settings, st  # optional-hypothesis shim
 
 from repro.training import checkpoint as CK
 from repro.training import data as data_mod
-from repro.training.fault import StragglerWatchdog, run_training
+from repro.training.fault import run_training
 from repro.training.optimizer import (
     AdamWConfig,
     adamw_init,
@@ -256,12 +256,3 @@ def test_crash_recovery_resumes_exactly():
         assert report.resumed_from in (4, 6)
         _, final, _ = CK.restore_checkpoint(d, params2)
         np.testing.assert_allclose(np.asarray(final["w"]), np.asarray(ref["w"]))
-
-
-def test_straggler_watchdog_flags_slow_steps():
-    wd = StragglerWatchdog(alpha=0.5, threshold=2.0, warmup_steps=0)
-    flagged = []
-    for i, dt in enumerate([0.1, 0.1, 0.1, 0.5, 0.1]):
-        flagged.append(wd.observe(i, dt))
-    assert flagged == [False, False, False, True, False]
-    assert len(wd.events) == 1 and wd.events[0]["step"] == 3
